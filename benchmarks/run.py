@@ -434,7 +434,10 @@ def fuzz_heavy(examples: int = 200) -> int:
     deep pass; PR CI runs the bounded default via plain pytest)."""
     import subprocess
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, REPRO_FUZZ_EXAMPLES=str(examples))
+    # the child only runs tests: keep it off any accelerator this
+    # process may hold
+    env = dict(os.environ, REPRO_FUZZ_EXAMPLES=str(examples),
+               JAX_PLATFORMS="cpu")
     return subprocess.call(
         [sys.executable, "-m", "pytest", "-q",
          os.path.join(root, "tests", "test_fault_fuzz.py")], env=env)
@@ -539,6 +542,8 @@ if __name__ == "__main__":
                              "example count (default 200) instead of the "
                              "benchmark sections")
     args = parser.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main(smoke=args.smoke, bench_json=args.bench_json,
                   fast=not args.legacy_datapath,
                   matrix_md=args.matrix_md,

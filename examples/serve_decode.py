@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro import configs as C
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import ServeEngine, TPServeEngine
 
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--channels", type=int, default=1,
                     help="rails to stripe the TP collectives across")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = C.smoke_config(args.arch)
     model = build_model(cfg)

@@ -18,6 +18,7 @@ from repro import configs as C
 from repro.collectives import JcclWorld
 from repro.core import shift as S
 from repro.core.fabric import build_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import DDPTrainer, RestartNeeded, TrainerConfig, \
     resume_training
 
@@ -36,6 +37,7 @@ def main():
                     help="StandardLib (crash + checkpoint-restart) instead "
                          "of SHIFT")
     args = ap.parse_args()
+    enable_compile_cache()
     steps = args.steps or (200 if args.full else 60)
     fail_at = args.fail_at or steps // 3
 

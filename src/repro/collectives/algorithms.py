@@ -22,7 +22,8 @@ independent, so they ride different rails concurrently):
 
 * all-reduce / reduce-scatter — **buckets**: each bucket runs the full
   ring pipeline on its home channel.
-* all-gather — **shards**: each shard's trip around the ring is a chain.
+* all-gather — **shard pieces**: each ``max_chunk_bytes`` piece of a
+  shard travels the ring as its own chain.
 * broadcast — **chunks**: each pipeline chunk travels the root chain.
 * all-to-all — **row chunks**: each (src, dst) row is split into
   ``max_chunk_bytes`` chunks with per-chunk tags/home channels, so one
@@ -178,9 +179,12 @@ class _RingAllReduce(_Collective):
 
 
 class _RingAllGather(_Collective):
-    """Ring all-gather over variable-size shards. Each shard's trip
-    around the ring is an independent chain (tag = shard index), so the
-    n shards stripe across channels and pipeline concurrently."""
+    """Ring all-gather over variable-size shards. Each shard is cut into
+    ``max_chunk_bytes`` pieces (one empty piece for an empty shard) and
+    each piece's trip around the ring is an independent chain, tag =
+    ``piece * n_ranks + shard`` — so a shard that fits one chunk keeps
+    tag = shard index, and the chains stripe across channels and
+    pipeline concurrently."""
 
     kind = "all_gather"
 
@@ -188,21 +192,28 @@ class _RingAllGather(_Collective):
         super().__init__(world)
         self.full = [f.reshape(-1) for f in full]
         self.sizes = sizes
-        self.offsets = np.concatenate([[0], np.cumsum(sizes)]).astype(int)
+        offsets = np.concatenate([[0], np.cumsum(sizes)]).astype(int)
         self.dtype = self.full[0].dtype
         self.itemsize = self.dtype.itemsize
         n = world.n_ranks
-        self.remaining = [n - 1] * n    # shards each rank still awaits
+        per = max(1, world.max_chunk_bytes // self.itemsize)
+        self.pieces = [
+            [(lo, min(lo + per, o1)) for lo in range(o0, o1, per)]
+            or [(o0, o0)]
+            for o0, o1 in zip(offsets[:-1], offsets[1:])]
+        # pieces each rank still awaits: every other shard's
+        self.remaining = [sum(len(p) for s, p in enumerate(self.pieces)
+                              if s != r) for r in range(n)]
         self.done_ranks = 0
 
-    def _forward(self, rank: int, shard: int) -> None:
+    def _forward(self, rank: int, shard: int, piece: int) -> None:
         n = self.world.n_ranks
         nxt = (rank + 1) % n
         if nxt == shard:
-            return  # the shard is back at its origin: chain complete
-        o0, o1 = self.offsets[shard], self.offsets[shard + 1]
-        self._send(rank, nxt, self.full[rank][o0:o1],
-                   tag=shard, home=shard)
+            return  # the piece is back at its origin: chain complete
+        p0, p1 = self.pieces[shard][piece]
+        tag = piece * n + shard
+        self._send(rank, nxt, self.full[rank][p0:p1], tag=tag, home=tag)
 
     def start(self) -> None:
         n = self.world.n_ranks
@@ -210,24 +221,24 @@ class _RingAllGather(_Collective):
             self.done_ranks = 1
             return
         for r in range(n):
-            self._forward(r, r)     # launch this rank's own shard
-
+            for piece in range(len(self.pieces[r])):
+                self._forward(r, r, piece)  # this rank's own shard
 
     def on_notify(self, rank: int, peer: int, tag, ep, seq: int) -> None:
         n = self.world.n_ranks
-        if peer != (rank - 1) % n or not isinstance(tag, int):
+        if peer != (rank - 1) % n or not isinstance(tag, int) or tag < 0:
             return
-        if not 0 <= tag < n:
-            return  # foreign tag: no such shard
-        shard = tag
-        o0, o1 = self.offsets[shard], self.offsets[shard + 1]
+        piece, shard = divmod(tag, n)
+        if piece >= len(self.pieces[shard]):
+            return  # foreign tag: no such shard piece
+        p0, p1 = self.pieces[shard][piece]
         stage = ep.staging_slot_view(
-            peer, seq, (o1 - o0) * self.itemsize).view(self.dtype)
-        self.full[rank][o0:o1] = stage
+            peer, seq, (p1 - p0) * self.itemsize).view(self.dtype)
+        self.full[rank][p0:p1] = stage
         self.remaining[rank] -= 1
         if self.remaining[rank] == 0:
             self.done_ranks += 1
-        self._forward(rank, shard)
+        self._forward(rank, shard, piece)
 
     def done(self) -> bool:
         return self.done_ranks == self.world.n_ranks

@@ -162,9 +162,13 @@ class RankEndpoint:
         downstream of THIS chunk's delivery, so a still-pending (unposted)
         send can never be overwritten. A held view therefore suffices; no
         defensive copy."""
+        raw = payload.view(np.uint8).ravel()
+        if raw.nbytes > self.world.max_chunk_bytes:
+            # it would spill into the neighbouring FIFO/staging slots
+            raise ValueError(f"chunk of {raw.nbytes} B exceeds the "
+                             f"{self.world.max_chunk_bytes} B slot")
         seq = self.enqueue_seq[peer]
         self.enqueue_seq[peer] = seq + 1
-        raw = payload.view(np.uint8).ravel()
         if self.send_seq[peer] - self.send_completed[peer] >= self.K:
             self.pending_sends[peer].append(raw)
             return seq

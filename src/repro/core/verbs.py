@@ -431,6 +431,8 @@ class QP:
         self.retry_cnt = pd.ctx.cluster.retry_cnt
         self.rnr_retry = pd.ctx.cluster.rnr_retry
         self._serializing = 0  # count of in-progress serializations
+        # legacy datapath: latest arrival time of a scheduled ACK/response
+        self.ack_horizon = 0.0
         # Epoch guards: a QP reset invalidates every in-flight transport
         # event referencing the old rings (prevents 'ghost' deliveries).
         self.epoch = 0
@@ -502,6 +504,7 @@ class QP:
         self.next_psn = 0
         self.epsn = 0
         self._serializing = 0
+        self.ack_horizon = 0.0
         self.epoch += 1
         self.state = QPState.RESET
 
@@ -1165,6 +1168,12 @@ class Context:
         if isinstance(read_data, (bytes, bytearray)) and wqe.opcode is Opcode.READ:
             # response carries data: serialize at the responder NIC
             lat += len(read_data) / max(dst_nic.effective_bandwidth(), 1.0)
+        # the responder answers in request order, so an ACK never
+        # overtakes an earlier READ response still serializing (RC
+        # completions then leave in posting order, as on the fast path)
+        if src_qp.ack_horizon > self.sim.now + lat:
+            lat = src_qp.ack_horizon - self.sim.now
+        src_qp.ack_horizon = self.sim.now + lat
         self.sim.call(lat, self._ack_arrive, src_qp, wqe, dst_nic, rnr,
                       read_data, epoch)
 

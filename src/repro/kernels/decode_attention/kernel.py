@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import interpret_default
 
 NEG_INF = -1e30
 
@@ -57,8 +57,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, scale=None,
-                     bk: int = 512, interpret: bool = True):
+                     bk: int = 512, interpret=None):
     """q: (B,H,hd); caches: (B,KV,S,hd); cache_len: scalar int32."""
+    interpret = interpret_default(interpret)
     B, H, hd = q.shape
     KV, S0 = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
@@ -87,7 +88,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, scale=None,
                         pltpu.VMEM((1,), jnp.float32),
                         pltpu.VMEM((1, hd), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, q4, k_cache, v_cache)

@@ -7,7 +7,10 @@ Grid design (TPU): ``(B, H, nq, nk)`` with the KV axis innermost and
 scratch that persists across the innermost grid steps (the canonical TPU
 flash pattern). Block shapes are the VMEM working set: (bq, hd) for Q/acc
 and (bk, hd) for K/V; MXU-aligned when bq/bk/hd are multiples of 128 on
-real hardware (tests use smaller interpret-mode blocks).
+real hardware (tests use smaller interpret-mode blocks). Per-row values
+(the softmax max/sum, ``lse`` and ``delta``) are (rows, 1) columns, in
+scratch and in HBM alike: the TPU lowering takes a block whose last two
+dims tile as (8, 128) or span the array, which a trailing unit dim does.
 """
 
 from __future__ import annotations
@@ -19,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import interpret_default
 
 NEG_INF = -1e30
 
@@ -54,14 +57,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         valid = valid & (k_pos <= q_pos)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_scr[...]                                   # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     p = jnp.where(valid, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_scr[...] * corr + p.sum(axis=1)
+    l_new = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
     v = v_ref[0, 0].astype(jnp.float32)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -69,7 +72,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_scr[...] + jnp.log(l)).astype(lse_ref.dtype)
 
 
@@ -84,7 +87,10 @@ def _pad_to(x, axis: int, mult: int):
 
 
 def flash_fwd(q, k, v, *, causal: bool = True, scale=None,
-              bq: int = 128, bk: int = 128, interpret: bool = True):
+              bq: int = 128, bk: int = 128, interpret=None):
+    """q: (B,H,Sq,hd); k, v: (B,KV,Sk,hd) -> (o (B,H,Sq,hd),
+    lse (B,H,Sq) float32)."""
+    interpret = interpret_default(interpret)
     B, H, Sq0, hd = q.shape
     KV, Sk0 = k.shape[1], k.shape[2]
     G = H // KV
@@ -102,7 +108,7 @@ def flash_fwd(q, k, v, *, causal: bool = True, scale=None,
                                causal=causal, scale=scale)
     out_shapes = (
         jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-        jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+        jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -114,20 +120,20 @@ def flash_fwd(q, k, v, *, causal: bool = True, scale=None,
         ],
         out_specs=(
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         out_shape=out_shapes,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-    return o[:, :, :Sq0], lse[:, :, :Sq0]
+    return o[:, :, :Sq0], lse[:, :, :Sq0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -160,11 +166,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     valid = k_pos < sk
     if causal:
         valid = valid & (k_pos <= q_pos)
-    p = jnp.exp(jnp.where(valid, s, NEG_INF) - lse[:, None])
+    p = jnp.exp(jnp.where(valid, s, NEG_INF) - lse)
     p = jnp.where(valid, p, 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dq_scr[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
 
@@ -206,14 +212,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     valid = k_pos < sk
     if causal:
         valid = valid & (k_pos <= q_pos)
-    p = jnp.exp(jnp.where(valid, s, NEG_INF) - lse[:, None])
+    p = jnp.exp(jnp.where(valid, s, NEG_INF) - lse)
     p = jnp.where(valid, p, 0.0)
     # dV += P^T dO
     dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     # dK += dS^T Q  (note Q already carries `scale`; dK needs raw Q)
     dk_scr[...] += jax.lax.dot_general(ds, q / scale,
                                        (((0,), (0,)), ((), ())),
@@ -226,7 +232,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
-              bq: int = 128, bk: int = 128, interpret: bool = True):
+              bq: int = 128, bk: int = 128, interpret=None):
+    """Gradients (dq, dk, dv) of :func:`flash_fwd` for output
+    cotangent ``do``; ``o`` and ``lse`` (B,H,Sq) are its outputs."""
+    interpret = interpret_default(interpret)
     B, H, Sq0, hd = q.shape
     KV, Sk0 = k.shape[1], k.shape[2]
     G = H // KV
@@ -238,12 +247,12 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
     # padded q rows: lse pads must be huge so p = exp(s - lse) == 0 there
     pad_q = q.shape[2] - Sq0
     lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)),
-                  constant_values=-NEG_INF)
+                  constant_values=-NEG_INF)[..., None]
     Sq, Sk = q.shape[2], k.shape[2]
     nq = pl.cdiv(Sq, bq)
     nk = pl.cdiv(Sk, bk)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1)  # (B, H, Sq)
+                    axis=-1, keepdims=True)  # (B, H, Sq, 1)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, sk=Sk0,
@@ -254,13 +263,13 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j, G=G: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j, G=G: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -269,9 +278,6 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
     def qh_index(b, c, j, inner, G=G, nq=nq):
         # inner enumerates (g, iq); q head = c * G + g
         return (b, c * G + inner // nq, inner % nq, 0)
-
-    def qh_index3(b, c, j, inner, G=G, nq=nq):
-        return (b, c * G + inner // nq, inner % nq)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, sk=Sk0, nq=nq, G=G,
@@ -282,8 +288,8 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
             pl.BlockSpec((1, 1, bk, hd), lambda b, c, j, inner: (b, c, j, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, c, j, inner: (b, c, j, 0)),
             pl.BlockSpec((1, 1, bq, hd), qh_index),
-            pl.BlockSpec((1, 1, bq), qh_index3),
-            pl.BlockSpec((1, 1, bq), qh_index3),
+            pl.BlockSpec((1, 1, bq, 1), qh_index),
+            pl.BlockSpec((1, 1, bq, 1), qh_index),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, bk, hd), lambda b, c, j, inner: (b, c, j, 0)),
@@ -293,7 +299,7 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
                         pltpu.VMEM((bk, hd), jnp.float32)],
         out_shape=(jax.ShapeDtypeStruct((B, KV, Sk, hd), k.dtype),
                    jax.ShapeDtypeStruct((B, KV, Sk, hd), v.dtype)),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

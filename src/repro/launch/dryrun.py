@@ -191,7 +191,7 @@ def _compile_pass(cfg, shape: C.Shape, mesh,
                                 jax.ShapeDtypeStruct((2,), jnp.uint32))
     pspecs = SH.param_specs(cfg, params_sds, mesh)
     p_shard = SH.to_named(pspecs, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = AdamWConfig(moment_dtype=jnp.bfloat16,
                                   **(opt_overrides or {}))

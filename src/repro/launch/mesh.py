@@ -16,12 +16,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     ('pod', 'data', 'model') 2x16x16 with ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many devices exist (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings
+    from the inputs and the models' ``constrain`` hints."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple:

@@ -172,25 +172,12 @@ def causal_mask_logits(scores: jnp.ndarray, q_pos: jnp.ndarray,
 
 
 def ambient_mesh_axes() -> dict:
-    """{axis_name: size} of the ambient mesh, or {} when not under one.
-
-    Checks the new-style abstract mesh first, then the classic
-    ``with mesh:`` thread-resources context.
-    """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return dict(zip(pm.axis_names, pm.devices.shape))
-    except Exception:
-        pass
-    return {}
+    """{axis_name: size} of the mesh set by ``jax.set_mesh``, or {}
+    when there is none."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return {}
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def model_axis_size() -> int:
@@ -203,7 +190,8 @@ def dp_axis_names() -> tuple:
 
 
 def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
-    """with_sharding_constraint that degrades to identity off-mesh."""
+    """with_sharding_constraint on the ambient mesh; identity off-mesh.
+    Axes the mesh lacks, or that do not divide the dim, stay unsharded."""
     axes = ambient_mesh_axes()
     if not axes:
         return x
@@ -216,8 +204,5 @@ def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
         for a in (ax if isinstance(ax, tuple) else (ax,)):
             size *= axes.get(a, 1)
         fixed.append(ax if size > 1 and dim % size == 0 else None)
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*fixed))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*fixed))

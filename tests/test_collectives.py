@@ -67,6 +67,23 @@ def test_allgather_and_broadcast_and_a2a():
             np.testing.assert_array_equal(outs[j][i], mats[i][j])
 
 
+@pytest.mark.parametrize("fault", [False, True])
+def test_allgather_shards_larger_than_a_chunk(fault):
+    # a serving logits shard (vocab slice x batch) can exceed the wire
+    # chunk: each shard must travel as max_chunk_bytes pieces
+    c, w = make_world(n_ranks=3, max_chunk_bytes=4096)
+    shards = [np.arange(4096 // 4 * 5 + 7 * r, dtype=np.float32) + 1e4 * r
+              for r in range(3)]
+    if fault:
+        c.sim.at(c.sim.now + 2e-5, c.fail_nic, "host1/mlx5_0")
+    full = w.all_gather(shards)
+    expect = np.concatenate(shards)
+    for f in full:
+        np.testing.assert_array_equal(f, expect)
+    fallbacks = sum(ep.lib.stats.fallbacks for ep in w.endpoints)
+    assert (fallbacks > 0) == fault
+
+
 def test_reduce_scatter_owned_chunks():
     c, w = make_world(n_ranks=4)
     arrays = [np.arange(64, dtype=np.int64) for _ in range(4)]

@@ -145,6 +145,15 @@ def test_all_opcodes_byte_identical_fast_vs_legacy():
     assert fast[3] == slow[3], "recv WC stream differs"
 
 
+def test_write_ack_does_not_overtake_read_response():
+    # a long READ response still serializes when the next WRITE's ACK is
+    # sent; RC completions must still leave in posting order on both paths
+    script = [("READ", 1884, 0, 0), ("WRITE", 8, 0, 0)]
+    slow = _run_script(False, script)
+    assert [wr_id for wr_id, _, _ in slow[2]] == [0, 1]
+    assert _run_script(True, script) == slow
+
+
 @given(st.lists(st.tuples(st.sampled_from(OPS),
                           st.integers(min_value=8, max_value=2048),
                           st.integers(min_value=0, max_value=50),

@@ -50,7 +50,7 @@ def test_train_step_lowers_on_mesh(arch):
     batch = {"tokens": jax.ShapeDtypeStruct((2, 17), jnp.int32)}
     bspecs = SH.batch_specs(cfg, mesh, 2)
     b_shard = SH.to_named({"tokens": bspecs["tokens"]}, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         step = make_train_step(model, opt_cfg)
         lowered = jax.jit(step, in_shardings=(p_shard, o_shard, b_shard),
                           out_shardings=(p_shard, o_shard, None)).lower(
@@ -67,7 +67,7 @@ def test_decode_step_lowers_with_cache_specs(arch):
     cspecs = SH.cache_specs(cfg, cache_sds, mesh, 2)
     c_shard = SH.to_named(cspecs, mesh)
     toks = jax.ShapeDtypeStruct((2, 1), jnp.int32)
-    with mesh:
+    with jax.set_mesh(mesh):
         step = make_decode_step(model)
         lowered = jax.jit(step, in_shardings=(p_shard, c_shard, None),
                           donate_argnums=(1,)).lower(
