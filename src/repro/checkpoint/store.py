@@ -46,6 +46,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import tracing
+
 _MARKER = "committed"
 
 
@@ -55,6 +57,8 @@ def _flatten(tree) -> Dict[str, np.ndarray]:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
         flat[key] = np.asarray(leaf)
+        if isinstance(leaf, jax.Array):
+            tracing.add(d2h_bytes=leaf.nbytes)
     return flat
 
 
@@ -139,6 +143,13 @@ class CheckpointStore:
         shutil.rmtree(final, ignore_errors=True)
 
     def save(self, step: int, tree, metadata: Optional[dict] = None) -> str:
+        """Checkpoint ``tree`` as ``step``: snapshot it to the host on the
+        caller's thread (span ``ckpt.save``), then write it, on a thread
+        of its own where ``async_save`` is set. Returns the directory."""
+        with tracing.span("ckpt.save", step=step):
+            return self._save(step, tree, metadata)
+
+    def _save(self, step: int, tree, metadata: Optional[dict]) -> str:
         flat = _flatten(tree)  # snapshot on the caller's thread
         self._stream_background(flat)
 

@@ -36,6 +36,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.fabric import Cluster
 from repro.core.shift import ShiftLib, StandardLib
 
@@ -396,6 +397,15 @@ class JcclWorld:
         class) so mixed-load timeouts are attributable. Returns
         ``works`` for chaining.
         """
+        events = self.sim._executed
+        with tracing.span("jccl.wait_all"):
+            try:
+                self._pump(works, timeout)
+            finally:
+                tracing.add(events=self.sim._executed - events)
+        return works
+
+    def _pump(self, works: Sequence[Work], timeout: Optional[float]) -> None:
         if timeout is None:
             timeout = self.wait_timeout
         deadline = self.sim.now + timeout
@@ -423,7 +433,6 @@ class JcclWorld:
                 raise exc
             self.sim.step()
             pending = [w for w in pending if not w.done()]
-        return works
 
     @property
     def any_shift(self) -> bool:

@@ -27,9 +27,11 @@ from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
+
+from repro import tracing
 
 QUEUED, ACTIVE, DONE, FAILED = "queued", "active", "done", "failed"
 
@@ -58,6 +60,8 @@ class RequestScheduler:
         self.requests: List[Request] = []
         self.decode_steps = 0
         self._feed = np.zeros(n_slots, dtype=np.int32)
+        # each queued request's ``sched.queued`` interval, by rid
+        self._queued: Dict[int, list] = {}
 
     def submit(self, prompt: np.ndarray, n_tokens: int) -> Request:
         """Enqueue a request; it is admitted when a slot frees up."""
@@ -68,6 +72,7 @@ class RequestScheduler:
                       n_tokens=n_tokens)
         self.requests.append(req)
         self.queue.append(req)
+        self._queued[req.rid] = tracing.begin("sched.queued", rid=req.rid)
         return req
 
     @property
@@ -85,25 +90,28 @@ class RequestScheduler:
         step. Returns :attr:`pending` (False once everything drained).
         Raises ``CollectiveError`` if the fabric aborts mid-step —
         callers handle it via :meth:`fail_outstanding`."""
-        for slot in range(self.n_slots):
-            if self.slots[slot] is None and self.queue:
-                req = self.queue.popleft()
-                req.slot, req.state = slot, ACTIVE
-                self.slots[slot] = req
-                tok = self.engine.admit(slot, req.prompt)
-                req.tokens.append(tok)
-                self._feed[slot] = tok
-                self._maybe_finish(req)
-        if any(r is not None for r in self.slots):
-            toks = self.engine.decode_batch(self._feed.copy())
-            self.decode_steps += 1
-            for slot, req in enumerate(list(self.slots)):
-                if req is None:
-                    continue
-                tok = int(toks[slot])
-                req.tokens.append(tok)
-                self._feed[slot] = tok
-                self._maybe_finish(req)
+        with tracing.span("sched.tick"):
+            for slot in range(self.n_slots):
+                if self.slots[slot] is None and self.queue:
+                    req = self.queue.popleft()
+                    tracing.end(self._queued.pop(req.rid))
+                    req.slot, req.state = slot, ACTIVE
+                    self.slots[slot] = req
+                    with tracing.span("sched.admit", rid=req.rid):
+                        tok = self.engine.admit(slot, req.prompt)
+                    req.tokens.append(tok)
+                    self._feed[slot] = tok
+                    self._maybe_finish(req)
+            if any(r is not None for r in self.slots):
+                toks = self.engine.decode_batch(self._feed.copy())
+                self.decode_steps += 1
+                for slot, req in enumerate(list(self.slots)):
+                    if req is None:
+                        continue
+                    tok = int(toks[slot])
+                    req.tokens.append(tok)
+                    self._feed[slot] = tok
+                    self._maybe_finish(req)
         return self.pending
 
     def fail_outstanding(self) -> int:
@@ -117,6 +125,9 @@ class RequestScheduler:
                 n += 1
         self.slots = [None] * self.n_slots
         self.queue.clear()
+        for handle in self._queued.values():
+            tracing.end(handle, failed=1)
+        self._queued.clear()
         return n
 
     def run(self, max_steps: int = 10_000) -> None:

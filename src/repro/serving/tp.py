@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.models import LM
 
 from .engine import ServeEngine
@@ -99,6 +100,7 @@ class TPServeEngine:
         vector), K and V concatenated per layer."""
         k = np.asarray(cache["k"])
         v = np.asarray(cache["v"])
+        tracing.add(d2h_bytes=k.nbytes + v.nbytes)
         S = k.shape[2]
         pl = np.asarray(prev_len)
         if pl.ndim == 0:
@@ -159,10 +161,13 @@ class TPServeEngine:
         self.sync_rounds += 1
         if self.world is None:
             return logits
-        lg = np.ascontiguousarray(np.asarray(logits))
+        with tracing.span("tp.logits_to_host"):
+            lg = np.ascontiguousarray(np.asarray(logits))
+            tracing.add(d2h_bytes=lg.nbytes)
         payloads = {"logits": _bytes_of(lg)}
         if cache is not None and prev_len is not None:
-            payloads.update(self._step_kv_bytes(cache, prev_len))
+            with tracing.span("tp.kv_rows"):
+                payloads.update(self._step_kv_bytes(cache, prev_len))
         works = {name: self.world.gather_replicated_async(
                      b, priority="latency_critical")
                  for name, b in payloads.items()}
@@ -171,13 +176,15 @@ class TPServeEngine:
             moe = self._expert_dispatch(payloads["kv0"])
         batch = list(works.values()) + ([moe[1]] if moe else [])
         self.world.wait_all(batch, timeout=self.timeout)
-        for name, b in payloads.items():
-            for rec in works[name].result():
-                if not np.array_equal(rec, b):
-                    self.reconstruction_mismatches += 1
-        if moe is not None:
-            self._expert_combine(*moe)
+        with tracing.span("tp.verify"):
+            for name, b in payloads.items():
+                for rec in works[name].result():
+                    if not np.array_equal(rec, b):
+                        self.reconstruction_mismatches += 1
+            if moe is not None:
+                self._expert_combine(*moe)
         rec0 = works["logits"].result()[0]
+        tracing.add(h2d_bytes=rec0.nbytes)
         return jnp.asarray(rec0.view(lg.dtype).reshape(lg.shape))
 
     # -- static batch generation -------------------------------------------
@@ -250,17 +257,21 @@ class TPServeEngine:
         if not 1 <= n <= self._prefill_len:
             raise ValueError(f"prompt length {n} outside "
                              f"[1, {self._prefill_len}]")
-        padded = np.zeros((1, self._prefill_len), np.int32)
-        padded[0, :n] = prompt
-        logits, pcache = self._local._prefill(
-            self.params, jnp.asarray(padded),
-            jnp.asarray([n - 1], np.int32))
-        c = self._cache
-        c["k"] = c["k"].at[:, slot].set(pcache["k"][:, 0])
-        c["v"] = c["v"].at[:, slot].set(pcache["v"][:, 0])
-        c["len"] = c["len"].at[slot].set(n)
-        rec = self._sync(logits)
-        return int(np.asarray(jnp.argmax(rec[:, -1], axis=-1))[0])
+        with tracing.span("tp.admit"):
+            padded = np.zeros((1, self._prefill_len), np.int32)
+            padded[0, :n] = prompt
+            last = np.asarray([n - 1], np.int32)
+            tracing.add(h2d_bytes=padded.nbytes + last.nbytes)
+            logits, pcache = self._local._prefill(
+                self.params, jnp.asarray(padded), jnp.asarray(last))
+            c = self._cache
+            c["k"] = c["k"].at[:, slot].set(pcache["k"][:, 0])
+            c["v"] = c["v"].at[:, slot].set(pcache["v"][:, 0])
+            c["len"] = c["len"].at[slot].set(n)
+            rec = self._sync(logits)
+            tok = np.asarray(jnp.argmax(rec[:, -1], axis=-1))
+            tracing.add(d2h_bytes=tok.nbytes)
+            return int(tok[0])
 
     def decode_batch(self, feed: np.ndarray) -> np.ndarray:
         """One decode step over the whole slot batch. ``feed`` is the
@@ -273,8 +284,12 @@ class TPServeEngine:
         feed = np.asarray(feed, dtype=np.int32).reshape(-1)
         if feed.size != self._n_slots:
             raise ValueError(f"feed size {feed.size} != {self._n_slots}")
-        prev_len = np.asarray(self._cache["len"])
-        logits, self._cache = self._local._decode(
-            self.params, self._cache, jnp.asarray(feed)[:, None])
+        with tracing.span("tp.decode"):
+            prev_len = np.asarray(self._cache["len"])
+            tracing.add(d2h_bytes=prev_len.nbytes, h2d_bytes=feed.nbytes)
+            logits, self._cache = self._local._decode(
+                self.params, self._cache, jnp.asarray(feed)[:, None])
         rec = self._sync(logits, self._cache, prev_len)
-        return np.asarray(jnp.argmax(rec[:, -1], axis=-1)).astype(np.int32)
+        toks = np.asarray(jnp.argmax(rec[:, -1], axis=-1))
+        tracing.add(d2h_bytes=toks.nbytes)
+        return toks.astype(np.int32)

@@ -26,13 +26,13 @@ simulated network time of the collectives.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.collectives import CollectiveError, JcclWorld
 from repro.core.shift import ShiftLib, StandardLib
 from repro.checkpoint import CheckpointStore
@@ -189,14 +189,61 @@ class DDPTrainer:
         sizes = [int(np.prod(s)) for s in shapes]
         vec = np.concatenate([np.asarray(l, np.float32).ravel()
                               for l in leaves])
+        tracing.add(d2h_bytes=sum(l.nbytes for l in leaves))
 
         def unflatten(v):
             out, off = [], 0
             for s, n in zip(shapes, sizes):
                 out.append(jnp.asarray(v[off:off + n].reshape(s)))
                 off += n
+            tracing.add(h2d_bytes=sum(o.nbytes for o in out))
             return jax.tree_util.tree_unflatten(treedef, out)
         return vec, unflatten
+
+    def _rank_grads(self, params, step: int):
+        """Every rank's loss and flat host gradient for ``step`` (int8
+        round-tripped where ``grad_compress`` is on): ``(losses,
+        grad_vecs, unflatten, compute_t)``. ``compute_t`` is the ranks'
+        span seconds over the world size: the ranks run in turn here
+        and at once on a cluster."""
+        losses, grad_vecs, unflatten, busy = [], [], None, 0.0
+        for r in range(self.n):
+            with tracing.span("trainer.loss_and_grad", rank=r) as sp:
+                batch = jnp.asarray(self.data[r].batch_at(step))
+                tracing.add(h2d_bytes=batch.nbytes)
+                loss, grads = self._grad_fn(params, {"tokens": batch})
+                losses.append(float(loss))
+                tracing.add(d2h_bytes=loss.nbytes)
+            busy += sp.seconds
+            with tracing.span("trainer.grads_to_host", rank=r) as sp:
+                vec, unflatten = self._flatten_grads(grads)
+                if self.tcfg.grad_compress:
+                    q, scale, self._err_fb[r] = int8_compress(
+                        vec, self._err_fb[r])
+                    vec = int8_decompress(q, scale)
+            busy += sp.seconds
+            grad_vecs.append(vec)
+        return losses, grad_vecs, unflatten, busy / self.n
+
+    def _step(self, world: JcclWorld, run: TrainRun, state,
+              step: int) -> Tuple[float, float, float]:
+        """One data-parallel step on ``state``, updated in place: the
+        ranks' gradients, their all-reduce, the optimizer. Returns the
+        ranks' mean loss, ``compute_t`` and the all-reduce's virtual
+        seconds."""
+        losses, grad_vecs, unflatten, compute_t = self._rank_grads(
+            state["params"], step)
+        sim0 = self.cluster.sim.now
+        with tracing.span("trainer.allreduce"):
+            self._allreduce_grads(world, run, grad_vecs)
+        comm_t = self.cluster.sim.now - sim0
+        run.comm_time += comm_t
+        run.step_grad_times.append(comm_t)
+        with tracing.span("trainer.optimizer"):
+            mean_grads = unflatten(grad_vecs[0] / self.n)
+            state["params"], state["opt"], _ = adamw_update(
+                state["params"], mean_grads, state["opt"], self.opt_cfg)
+        return float(np.mean(losses)), compute_t, comm_t
 
     def _grad_buckets(self, world: JcclWorld,
                       total_elems: int) -> List[Tuple[int, int]]:
@@ -364,70 +411,51 @@ class DDPTrainer:
 
         while step < tcfg.steps:
             try:
-                wall0 = time.time()
-                losses, grad_vecs, unflatten = [], [], None
-                for r in range(self.n):
-                    batch = {"tokens": jnp.asarray(self.data[r].batch_at(step))}
-                    loss, grads = self._grad_fn(state["params"], batch)
-                    losses.append(float(loss))
-                    vec, unflatten = self._flatten_grads(grads)
-                    if tcfg.grad_compress:
-                        q, scale, self._err_fb[r] = int8_compress(
-                            vec, self._err_fb[r])
-                        vec = int8_decompress(q, scale)
-                    grad_vecs.append(vec)
-                compute_t = (time.time() - wall0) / self.n
+                with tracing.span("trainer.step", step=step + 1):
+                    loss, compute_t, comm_t = self._step(world, run, state,
+                                                         step)
+                    step += 1
+                    t += compute_t + comm_t
+                    run.timeline.append((t, step, loss))
+                    if on_step is not None:
+                        on_step(step, t, loss)
 
-                sim0 = self.cluster.sim.now
-                self._allreduce_grads(world, run, grad_vecs)
-                comm_t = self.cluster.sim.now - sim0
-                run.comm_time += comm_t
-                run.step_grad_times.append(comm_t)
-
-                mean_grads = unflatten(grad_vecs[0] / self.n)
-                state["params"], state["opt"], _ = adamw_update(
-                    state["params"], mean_grads, state["opt"], self.opt_cfg)
-                step += 1
-                t += compute_t + comm_t
-                run.timeline.append((t, step, float(np.mean(losses))))
-                if on_step is not None:
-                    on_step(step, t, float(np.mean(losses)))
-
-                # failure-aware checkpointing (§4.4)
-                now_fallbacks = sum(l.stats.fallbacks for l in shift_libs)
-                if now_fallbacks > last_fallbacks:
-                    last_fallbacks = now_fallbacks
-                    if self.policy is None:
-                        ckpt_after_fallback_pending = True
-                if self.policy is not None:
-                    # policy-directed: the engine already decided (and
-                    # rate-limited) at the fallback events themselves —
-                    # the trainer saves its REAL state exactly when a
-                    # "checkpoint" decision is pending, and counts
-                    # shrink-world actuations (the engine excluded the
-                    # channels at the scheduler already)
-                    acts = self.policy.consume_trainer_actions()
-                    if acts["checkpoint"]:
-                        ckpt_after_fallback_pending = True
-                        run.policy_ckpts += 1
-                    if acts["shrink"]:
-                        run.policy_shrinks += 1
-                if step % tcfg.ckpt_every == 0 or ckpt_after_fallback_pending:
-                    self.store.save(step, state,
-                                    {"reason": "post-fallback"
-                                     if ckpt_after_fallback_pending
-                                     else "scheduled"})
-                    if (ckpt_after_fallback_pending
-                            and tcfg.stop_at_next_ckpt_after_fallback):
-                        # scenario (3): stop gracefully at the checkpoint,
-                        # reschedule, and resume on healthy hardware
-                        run.restarts += 1
-                        run.slowdown_reschedule += tcfg.reschedule_time_shift
-                        t += tcfg.reschedule_time_shift
+                    # failure-aware checkpointing (§4.4)
+                    now_fallbacks = sum(l.stats.fallbacks
+                                        for l in shift_libs)
+                    if now_fallbacks > last_fallbacks:
+                        last_fallbacks = now_fallbacks
+                        if self.policy is None:
+                            ckpt_after_fallback_pending = True
+                    if self.policy is not None:
+                        # policy-directed: the engine already decided (and
+                        # rate-limited) at the fallback events themselves
+                        # — the trainer saves its REAL state exactly when
+                        # a "checkpoint" decision is pending, and counts
+                        # shrink-world actuations (the engine excluded
+                        # the channels at the scheduler already)
+                        acts = self.policy.consume_trainer_actions()
+                        if acts["checkpoint"]:
+                            ckpt_after_fallback_pending = True
+                            run.policy_ckpts += 1
+                        if acts["shrink"]:
+                            run.policy_shrinks += 1
+                    if (step % tcfg.ckpt_every == 0
+                            or ckpt_after_fallback_pending):
+                        self.store.save(step, state,
+                                        {"reason": "post-fallback"
+                                         if ckpt_after_fallback_pending
+                                         else "scheduled"})
+                        if (ckpt_after_fallback_pending and
+                                tcfg.stop_at_next_ckpt_after_fallback):
+                            # scenario (3): stop gracefully at the
+                            # checkpoint, reschedule, and resume on
+                            # healthy hardware
+                            run.restarts += 1
+                            run.slowdown_reschedule += \
+                                tcfg.reschedule_time_shift
+                            t += tcfg.reschedule_time_shift
                         ckpt_after_fallback_pending = False
-                    else:
-                        ckpt_after_fallback_pending = False
-
             except CollectiveError:
                 # crash-stop: the job dies; checkpoint-restart baseline
                 run.restarts += 1
@@ -511,30 +539,15 @@ def resume_training(trainer: DDPTrainer, world: JcclWorld, rn: RestartNeeded,
     # against the crashed world are dropped, not waited
     trainer.store.attach_world(world)
     while step < tcfg.steps:
-        wall0 = time.time()
-        losses, grad_vecs, unflatten = [], [], None
-        for r in range(trainer.n):
-            batch = {"tokens": jnp.asarray(trainer.data[r].batch_at(step))}
-            loss, grads = trainer._grad_fn(state["params"], batch)
-            losses.append(float(loss))
-            vec, unflatten = trainer._flatten_grads(grads)
-            grad_vecs.append(vec)
-        compute_t = (time.time() - wall0) / trainer.n
-        sim0 = trainer.cluster.sim.now
-        trainer._allreduce_grads(world, run, grad_vecs)
-        comm_t = trainer.cluster.sim.now - sim0
-        run.comm_time += comm_t
-        run.step_grad_times.append(comm_t)
-        mean_grads = unflatten(grad_vecs[0] / trainer.n)
-        state["params"], state["opt"], _ = adamw_update(
-            state["params"], mean_grads, state["opt"], trainer.opt_cfg)
-        step += 1
-        t += compute_t + comm_t
-        run.timeline.append((t, step, float(np.mean(losses))))
-        if on_step is not None:
-            on_step(step, t, float(np.mean(losses)))
-        if step % tcfg.ckpt_every == 0:
-            trainer.store.save(step, state, {"reason": "scheduled"})
+        with tracing.span("trainer.step", step=step + 1):
+            loss, compute_t, comm_t = trainer._step(world, run, state, step)
+            step += 1
+            t += compute_t + comm_t
+            run.timeline.append((t, step, loss))
+            if on_step is not None:
+                on_step(step, t, loss)
+            if step % tcfg.ckpt_every == 0:
+                trainer.store.save(step, state, {"reason": "scheduled"})
     trainer.store.drain_stream()
     run.final_step = step
     return run
