@@ -55,6 +55,17 @@ def _bytes_of(a: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(a).reshape(-1).view(np.uint8)
 
 
+@jax.jit
+def _new_kv_rows(k, v, prev_len):
+    """Each sequence's cache row at ``prev_len`` (a scalar is every
+    sequence's), clipped to [0, S-1]: (L, 2, B, KVh, hd), K before V,
+    from (L, B, S, KVh, hd) caches."""
+    B, S = k.shape[1], k.shape[2]
+    at = jnp.clip(jnp.broadcast_to(prev_len, (B,)), 0, S - 1)
+    slots = jnp.arange(B)
+    return jnp.stack([k[:, slots, at], v[:, slots, at]], axis=1)
+
+
 class TPServeEngine:
     """Rank-sharded serving engine over a ``JcclWorld`` (or local-only).
 
@@ -97,23 +108,14 @@ class TPServeEngine:
     def _step_kv_bytes(self, cache, prev_len) -> Dict[str, np.ndarray]:
         """Per-layer bytes of the K/V rows this decode step wrote: the
         cache row at each sequence's pre-step length (scalar or (B,)
-        vector), K and V concatenated per layer."""
-        k = np.asarray(cache["k"])
-        v = np.asarray(cache["v"])
-        tracing.add(d2h_bytes=k.nbytes + v.nbytes)
-        S = k.shape[2]
+        vector), K and V concatenated per layer. The rows are cut on
+        the device and only they are copied to the host."""
         pl = np.asarray(prev_len)
-        if pl.ndim == 0:
-            at = min(int(pl), S - 1)
-            rows_k, rows_v = k[:, :, at], v[:, :, at]
-        else:
-            idx = np.clip(pl.astype(np.int64), 0, S - 1)
-            idx = idx[None, :, None, None, None]
-            rows_k = np.take_along_axis(k, idx, axis=2)[:, :, 0]
-            rows_v = np.take_along_axis(v, idx, axis=2)[:, :, 0]
-        return {f"kv{layer}": np.concatenate([_bytes_of(rows_k[layer]),
-                                              _bytes_of(rows_v[layer])])
-                for layer in range(k.shape[0])}
+        tracing.add(h2d_bytes=pl.nbytes)
+        rows = np.asarray(_new_kv_rows(cache["k"], cache["v"], pl))
+        tracing.add(d2h_bytes=rows.nbytes)
+        return {f"kv{layer}": _bytes_of(rows[layer])
+                for layer in range(rows.shape[0])}
 
     def _expert_dispatch(self, flat: np.ndarray):
         """Launch the MoE expert-dispatch all-to-all carrying the step's

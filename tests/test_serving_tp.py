@@ -92,6 +92,55 @@ def test_tp_continuous_batching_matches_local_reference(setup):
     assert eng.reconstruction_mismatches == 0
 
 
+def _host_kv_bytes(cache, prev_len):
+    """The K/V row cut made on the host from the whole cache: the
+    reference the device cut must match byte for byte."""
+    k = np.asarray(cache["k"])
+    v = np.asarray(cache["v"])
+    S = k.shape[2]
+    pl = np.asarray(prev_len)
+    if pl.ndim == 0:
+        at = min(int(pl), S - 1)
+        rows_k, rows_v = k[:, :, at], v[:, :, at]
+    else:
+        idx = np.clip(pl.astype(np.int64), 0, S - 1)
+        idx = idx[None, :, None, None, None]
+        rows_k = np.take_along_axis(k, idx, axis=2)[:, :, 0]
+        rows_v = np.take_along_axis(v, idx, axis=2)[:, :, 0]
+    return {f"kv{layer}": np.concatenate(
+                [np.ascontiguousarray(rows_k[layer]).reshape(-1).view(np.uint8),
+                 np.ascontiguousarray(rows_v[layer]).reshape(-1).view(np.uint8)])
+            for layer in range(k.shape[0])}
+
+
+KV_S = 7
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("prev_len", [
+    np.int32(3), np.int32(KV_S - 1), np.int32(KV_S + 2),
+    np.array([0, 3, 6], np.int32),
+    np.array([KV_S - 1, KV_S + 4, 2], np.int32)],
+    ids=["scalar", "scalar_last", "scalar_past", "vector",
+         "vector_last_and_past"])
+def test_step_kv_bytes_match_host_cut(dtype, prev_len):
+    """The rows cut on the device are the host cut's bytes, under the
+    same keys in the same order: the fabric gathers and verifies the
+    same payload as when the whole cache went to the host."""
+    model = build_model(gpt2_124m.smoke_config())
+    eng = TPServeEngine(model, None, max_len=KV_S)
+    L, B, KVh, hd = 2, 3, 2, 4
+    kk, kv = jax.random.split(jax.random.PRNGKey(0))
+    cache = {"k": jax.random.normal(kk, (L, B, KV_S, KVh, hd), dtype),
+             "v": jax.random.normal(kv, (L, B, KV_S, KVh, hd), dtype)}
+    got = eng._step_kv_bytes(cache, prev_len)
+    want = _host_kv_bytes(cache, prev_len)
+    assert list(got) == list(want)
+    for name in want:
+        assert got[name].dtype == np.uint8
+        assert np.array_equal(got[name], want[name])
+
+
 def test_tp_rejects_cacheless_families():
     cfg = gpt2_124m.smoke_config()
     cfg = cfg.__class__(**{**cfg.__dict__, "family": "rwkv6"})

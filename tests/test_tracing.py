@@ -201,7 +201,9 @@ def test_scheduler_over_tp_engine_records_kv_rows_and_queue():
     sched.run()
     hi = time.perf_counter()
     recs = tracing.records(lo, hi)
-    kv = eng._cache["k"].nbytes + eng._cache["v"].nbytes
+    # only the step's new K and V rows come to the host, one per slot
+    L, n_slots, _, KVh, hd = eng._cache["k"].shape
+    kv = 2 * L * n_slots * KVh * hd * eng._cache["k"].dtype.itemsize
     rows = _named(recs, "tp.kv_rows")
     assert rows and all(r[5]["d2h_bytes"] == kv for r in rows)
     ticks = {r[1] for r in _named(recs, "sched.tick")}
