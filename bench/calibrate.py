@@ -35,12 +35,13 @@ def variants_train(cell, driver):
     # a faulted mix is compared through the first step after the fault
     fault = cell.mix.get("fault")
     after = fault["after_window_steps"] + 1 if fault else 0
-    ref = reference.train_reference(cell.cfg, cell.mix, seed, K, after)
+    ref = reference.train_reference(cell.arch, cell.cfg, cell.mix, seed, K,
+                                    after)
     out = {}
     for v in ("bf16", "half_batch", "no_exchange") + (
             ("no_exchange_last",) if fault else ()):
-        bad = reference.train_reference(cell.cfg, cell.mix, seed, K, after,
-                                        variant=v)
+        bad = reference.train_reference(cell.arch, cell.cfg, cell.mix, seed,
+                                        K, after, variant=v)
         out[v] = driver.compare(bad, ref, cell.limits["moved_floor"])
     return out
 
@@ -51,10 +52,11 @@ def variants_serve(cell, sample):
     from bench import reference
     seed, V = cell.sub_seed("model"), cell.cfg["vocab_size"]
     L = cell.mix["max_len"]
-    params = reference.init_params(cell.cfg, seed)
-    ref = reference.served_logits(params, cell.cfg, sample, L)
-    low = reference.served_logits(reference.fp8_weights(params), cell.cfg,
-                                  sample, L, act=reference.fp8_round)
+    params = cell.arch.init_params(cell.cfg, seed)
+    ref = reference.served_logits(cell.arch, params, cell.cfg, sample, L)
+    low = reference.served_logits(cell.arch, reference.fp8_weights(params),
+                                  cell.cfg, sample, L,
+                                  act=reference.fp8_round)
     del params
     picked = [list(np.argmax(lg, axis=-1)) for lg in low]
     altered = [[(t + 1) % V for t in toks] for _, toks in sample]
@@ -73,13 +75,13 @@ def main(argv=None) -> int:
     a = p.parse_args(argv)
 
     from bench import run
-    from bench.harness import Profiler, Spans, load_cell
+    from bench.harness import Profiler, Spans, load_cell, load_module
     run.require_chips(1)
     run.enable_compile_cache(ROOT)
     for i in range(a.seeds):
         seed = a.first_seed + 7919 * i
         cell, _ = load_cell(ROOT, a.workload, seed, a.seconds)
-        driver = run.load_module(
+        driver = load_module(
             ROOT / "bench" / "drivers" / f"{cell.mix['driver']}.py",
             f"bench_driver_{cell.mix['driver']}")
         t0 = time.perf_counter()

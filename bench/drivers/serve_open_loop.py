@@ -17,8 +17,10 @@ the lead-in traffic, so that the window opens at steady occupancy.
 Correctness, once the window has closed and the program's state is
 freed: a sample of the finished requests drawn from the seed, with the
 longest among them, goes through the plain reference's full forward
-pass over prompt and served tokens; the compared number is the widest
-gap by which a served token's reference logit lies below the best one.
+pass over prompt and served tokens (the float32 model in the module of
+the configuration's architecture, ``bench/archs/``); the compared number
+is the widest gap by which a served token's reference logit lies below
+the best one.
 Also held: no request failed, every finished request has exactly its
 token count, no fabric reconstruction differed from the local bytes,
 and the fabric fell back as often as the mix's fault asks.
@@ -33,8 +35,7 @@ from typing import Any, Dict, List
 import numpy as np
 
 from bench import arrivals, reference
-from bench.harness import (Cell, device_memory_peak, model_config,
-                           percentile, wrap_wait_all)
+from bench.harness import Cell, device_memory_peak, percentile, wrap_wait_all
 
 
 def choose_sample(done, seed: int, min_tokens: int, max_requests: int):
@@ -63,7 +64,7 @@ def run(cell: Cell, spans, prof) -> Dict[str, Any]:
 
     mix, cfg = cell.mix, cell.cfg
     seed = cell.sub_seed("model")
-    model = build_model(model_config(cfg))
+    model = build_model(cell.arch.model_config(cfg))
     params = jax.jit(model.init)(jax.random.PRNGKey(seed))
     cluster, libs, world = build_world(
         n_ranks=mix["ranks"], nics_per_host=mix["nics_per_host"], fast=True)
@@ -203,8 +204,8 @@ def run(cell: Cell, spans, prof) -> Dict[str, Any]:
     del engine, sched, params, world, libs, cluster, admit_inner, \
         decode_inner
     gc.collect()
-    ref_params = reference.init_params(cfg, seed)
-    ref_logits = reference.served_logits(ref_params, cfg, sample,
+    ref_params = cell.arch.init_params(cfg, seed)
+    ref_logits = reference.served_logits(cell.arch, ref_params, cfg, sample,
                                          mix["max_len"])
     del ref_params
     gap = reference.widest_gap(ref_logits, [t for _, t in sample])

@@ -32,7 +32,7 @@ import numpy as np
 
 from bench import reference
 from bench.harness import (Cell, WindowClosed, device_memory_peak,
-                           leaf_paths, model_config, wrap_wait_all)
+                           leaf_paths, wrap_wait_all)
 
 
 def worst_gap(prog: List[float], ref: List[float],
@@ -101,8 +101,8 @@ def run(cell: Cell, spans, prof) -> Dict[str, Any]:
     tcfg = TrainerConfig(steps=mix["steps"], ckpt_every=mix["steps"] + 1,
                          ckpt_dir=ckpt.name, seed=seed,
                          lr=mix["optimizer"]["lr"])
-    trainer = DDPTrainer(cluster, libs, model_config(cell.cfg), tcfg,
-                         batch_per_rank=B, seq_len=L)
+    trainer = DDPTrainer(cluster, libs, cell.arch.model_config(cell.cfg),
+                         tcfg, batch_per_rank=B, seq_len=L)
 
     # keep a handle on the trainer's live state: it updates the dict that
     # _init_state returned in place, step by step
@@ -185,7 +185,8 @@ def run(cell: Cell, spans, prof) -> Dict[str, Any]:
     gc.collect()
     ckpt.cleanup()
 
-    ref = reference.train_reference(cell.cfg, mix, seed, K, last - K)
+    ref = reference.train_reference(cell.arch, cell.cfg, mix, seed, K,
+                                    last - K)
     nums = compare(prog, ref, cell.limits["moved_floor"])
     lim = cell.limits
     checks = [("loss_gap", nums["loss_gap"], lim["loss_gap"], "<="),

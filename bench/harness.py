@@ -1,18 +1,19 @@
 """Pieces every driver and reader shares: the cell a run measures, its
-seeds, the harness's host spans, the profiler window, and the device.
+architecture's module, its seeds, the harness's host spans, the profiler
+window, and the device.
 
-Nothing here imports the program under test except
-:func:`model_config`, which turns a configuration file into the
-program's ``ModelConfig``.
+Nothing here imports the program under test.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib.util
 import json
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -30,11 +31,12 @@ def load_json(path: Path) -> Any:
 @dataclasses.dataclass
 class Cell:
     """One run of one cell: the configuration and traffic files it
-    names, the limits of its correctness comparison, and the run's
-    arguments."""
+    names, the module of the configuration's architecture, the limits of
+    its correctness comparison, and the run's arguments."""
 
     name: str
     cfg: Dict[str, Any]
+    arch: ModuleType
     mix: Dict[str, Any]
     limits: Dict[str, Any]
     seed: int
@@ -63,26 +65,30 @@ def load_cell(root: Path, workload: str, seed: int,
     cfg = load_json(root / configs[w["config"]]["file"])
     mix = load_json(root / "bench" / "traffic" / f"{w['traffic']}.json")
     limits = load_json(root / "bench" / "limits" / f"{workload}.json")
-    return Cell(workload, cfg, mix, limits, seed, seconds), bench
+    return Cell(workload, cfg, arch(root, cfg), mix, limits, seed,
+                seconds), bench
 
 
-def model_config(cfg: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file, whose
-    keys follow the model's published ``config.json``."""
-    import jax.numpy as jnp
+def arch(root: Path, cfg: Dict[str, Any]) -> ModuleType:
+    """The module ``<root>/bench/archs/<class>.py`` of the configuration's
+    architecture class, ``architectures[0]`` as in the model's published
+    ``config.json``: the program's ``ModelConfig``, the float32
+    reference and the counts of operations and bytes for that class."""
+    cls = cfg["architectures"][0]
+    path = root / "bench" / "archs" / f"{cls}.py"
+    if not path.is_file():
+        raise SystemExit(f"no module for architecture {cls!r} of "
+                         f"configuration {cfg['name']!r}: add "
+                         f"bench/archs/{cls}.py")
+    return load_module(path, f"bench_arch_{cls}")
 
-    from repro import configs as C
 
-    return C.get_config(
-        cfg["arch"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), act=cfg["hidden_act"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        param_dtype=getattr(jnp, cfg["param_dtype"]),
-        dtype=getattr(jnp, cfg["compute_dtype"]))
+def load_module(path: Path, name: str) -> ModuleType:
+    """The Python file ``path`` as a module named ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Spans:

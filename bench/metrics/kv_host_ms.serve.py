@@ -1,6 +1,6 @@
 """kv_host_ms.serve: host milliseconds per scheduler tick in the
-program's ``tp.kv_rows`` spans: the K/V cache to the host and the rows
-the decode step wrote cut from it."""
+program's ``tp.kv_rows`` spans: the K/V rows the decode step wrote, cut
+from the cache on the device, copied to the host."""
 
 from bench.program_spans import count, per, total
 

@@ -1,13 +1,17 @@
-"""Plain references for the benchmark's correctness comparison.
+"""Plain references for the benchmark's correctness comparison: what no
+architecture owns.
 
-Straightforward ``jax.numpy`` in float32 at ``Precision.HIGHEST``: a
-Llama-style decoder (RMSNorm, rotary positions on split halves, grouped
--query causal attention, SwiGLU MLP, untied head) as the configuration
-files describe it, its next-token loss and gradient, and AdamW with
-global-norm clipping, warm-up and a cosine schedule. Nothing here
-imports the program under test or takes anything it made: the initial
-weights and the training data are drawn again from the seed, by the
-same recipe the program follows, so both start from the same numbers.
+Straightforward ``jax.numpy`` in float32 at ``Precision.HIGHEST``. Each
+architecture's module in ``bench/archs/`` holds its own model: initial
+weights, forward pass and next-token loss. Here are the parts every
+architecture shares: the training data, AdamW with global-norm
+clipping, warm-up and a cosine schedule, blockwise causal attention,
+and the two drivers of the comparison, :func:`train_reference` and
+:func:`served_logits`, which take the architecture's module. Nothing
+here imports the program under test or takes anything it made: the
+initial weights and the training data are drawn again from the seed,
+by the same recipe the program follows, so both start from the same
+numbers.
 
 Attention runs over blocks of queries under ``jax.checkpoint`` and the
 loss one sequence at a time, so that the reference fits on one chip at
@@ -33,6 +37,7 @@ small size):
 from __future__ import annotations
 
 import math
+from types import ModuleType
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -44,51 +49,8 @@ F32 = jnp.float32
 
 
 # ---------------------------------------------------------------------------
-# weights and data, drawn from the seed
+# data, drawn from the seed
 # ---------------------------------------------------------------------------
-
-
-def _dense(key, shape, dtype):
-    """Normal weights with std 1/sqrt(shape[0]) (the first axis is the
-    fan-in), drawn in float32 and stored in ``dtype``."""
-    std = 1.0 / math.sqrt(shape[0])
-    return (jax.random.normal(key, shape, dtype=F32) * std).astype(dtype)
-
-
-def init_params(cfg: Dict[str, Any], seed: int, dtype=None):
-    """The initial weights for ``cfg`` from ``seed`` as one jitted call:
-    keys split eight ways (embedding, head, layers), per layer two ways
-    (attention, MLP), then four and three ways."""
-    dtype = dtype or getattr(jnp, cfg["param_dtype"])
-    D, V = cfg["hidden_size"], cfg["vocab_size"]
-    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd, F, L = cfg["head_dim"], cfg["intermediate_size"], \
-        cfg["num_hidden_layers"]
-
-    def block(k):
-        k1, k2 = jax.random.split(k)
-        a = list(jax.random.split(k1, 4))
-        m = list(jax.random.split(k2, 3))
-        return {"attn": {"wq": _dense(a[0], (D, H, hd), dtype),
-                         "wk": _dense(a[1], (D, KV, hd), dtype),
-                         "wv": _dense(a[2], (D, KV, hd), dtype),
-                         "wo": _dense(a[3], (H, hd, D), dtype)},
-                "ln1": jnp.ones((D,), dtype), "ln2": jnp.ones((D,), dtype),
-                "mlp": {"w_gate": _dense(m[0], (D, F), dtype),
-                        "w_up": _dense(m[1], (D, F), dtype),
-                        "w_down": _dense(m[2], (F, D), dtype)}}
-
-    def init(key):
-        keys = list(jax.random.split(key, 8))
-        p = {"embed": _dense(keys[0], (V, D), dtype),
-             "final_norm": jnp.ones((D,), dtype),
-             "blocks": jax.vmap(block)(
-                 jnp.stack(list(jax.random.split(keys[2], L))))}
-        if not cfg["tie_word_embeddings"]:
-            p["lm_head"] = _dense(keys[1], (D, V), dtype)
-        return p
-
-    return jax.jit(init)(jax.random.PRNGKey(seed))
 
 
 def synthetic_batch(vocab: int, seq_len: int, batch: int, rank: int,
@@ -112,26 +74,11 @@ def synthetic_batch(vocab: int, seq_len: int, batch: int, rank: int,
 
 
 # ---------------------------------------------------------------------------
-# the model
+# shared by the architectures' models
 # ---------------------------------------------------------------------------
 
 
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
-        * w.astype(F32)
-
-
-def _rope(x, pos, theta):
-    """Rotary positions on split halves; x (S, heads, hd)."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
-    ang = pos[:, None].astype(F32) * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _attention(q, k, v, q_block: int):
+def causal_attention(q, k, v, q_block: int):
     """Causal softmax attention, queries in blocks; q (S, H, hd), k and
     v (S, KV, hd) shared by H / KV query heads each."""
     S, H, hd = q.shape
@@ -156,7 +103,8 @@ def _attention(q, k, v, q_block: int):
     return out.reshape(nb * qb, H, hd)[:S]
 
 
-def _same(x):
+def identity(x):
+    """The ``act`` of a forward pass that rounds nothing."""
     return x
 
 
@@ -167,56 +115,6 @@ def fp8_round(a):
     s = jnp.maximum(jnp.max(jnp.abs(a32)), 1e-30) / 448.0
     return ((a32 / s).astype(jnp.float8_e4m3fn).astype(F32) * s
             ).astype(a.dtype)
-
-
-def hidden(params, tokens, cfg: Dict[str, Any], q_block: int = 512,
-           act=_same):
-    """Final-normed hidden states (S, D) of one sequence, in float32.
-    ``act`` rounds the input of every weight matrix product (the
-    serving control passes :func:`fp8_round`)."""
-    eps, theta = float(cfg["rms_norm_eps"]), float(cfg["rope_theta"])
-    w = lambda a: a.astype(F32)  # noqa: E731
-    x = params["embed"][tokens].astype(F32)
-    pos = jnp.arange(tokens.shape[0])
-
-    def layer(x, blk):
-        a, m = blk["attn"], blk["mlp"]
-        h = act(_rms(x, blk["ln1"], eps))
-        q = _rope(jnp.einsum("sd,dhk->shk", h, w(a["wq"]), precision=HI),
-                  pos, theta)
-        k = _rope(jnp.einsum("sd,dhk->shk", h, w(a["wk"]), precision=HI),
-                  pos, theta)
-        v = jnp.einsum("sd,dhk->shk", h, w(a["wv"]), precision=HI)
-        o = act(_attention(q, k, v, q_block))
-        x = x + jnp.einsum("shk,hkd->sd", o, w(a["wo"]), precision=HI)
-        h = act(_rms(x, blk["ln2"], eps))
-        g = jnp.dot(h, w(m["w_gate"]), precision=HI)
-        u = jnp.dot(h, w(m["w_up"]), precision=HI)
-        return x + jnp.dot(act(jax.nn.silu(g) * u), w(m["w_down"]),
-                           precision=HI), None
-
-    x, _ = jax.lax.scan(layer, x, params["blocks"])
-    return act(_rms(x, params["final_norm"], eps))
-
-
-def _head(params):
-    h = params.get("lm_head")
-    return params["embed"].T if h is None else h
-
-
-def sequence_loss(params, tokens, cfg: Dict[str, Any]):
-    """Mean next-token cross-entropy of one (S + 1,) token row."""
-    x = hidden(params, tokens[:-1], cfg)
-    logits = jnp.dot(x, _head(params).astype(F32), precision=HI)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, tokens[1:, None], axis=-1)[:, 0]
-    return jnp.mean(lse - picked)
-
-
-def sequence_logits(params, tokens, cfg: Dict[str, Any], act=_same):
-    """(S, V) float32 logits of one token row."""
-    return jnp.dot(hidden(params, tokens, cfg, act=act),
-                   _head(params).astype(F32), precision=HI)
 
 
 # ---------------------------------------------------------------------------
@@ -261,25 +159,27 @@ def leaf_norms(tree) -> List[float]:
         for l in jax.tree_util.tree_leaves(t)])(tree)]
 
 
-def train_reference(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
-                    checked: int, after: int = 0,
+def train_reference(arch: ModuleType, cfg: Dict[str, Any],
+                    mix: Dict[str, Any], seed: int, checked: int,
+                    after: int = 0,
                     variant: Optional[str] = None) -> Dict[str, Any]:
-    """``checked + after`` data-parallel steps of the plain reference (or
-    of a ``variant``) from ``seed``. Returns the mean loss of each step,
-    the per-leaf norms of the first step's gradient as AdamW takes it
-    (after clipping), of each leaf's change over the first ``checked``
-    steps (``change``) and, with ``after``, over the last step alone
-    (``last_change``)."""
+    """``checked + after`` data-parallel steps of the plain reference of
+    the architecture module ``arch`` (or of a ``variant``) from
+    ``seed``. Returns the mean loss of each step, the per-leaf norms of
+    the first step's gradient as AdamW takes it (after clipping), of
+    each leaf's change over the first ``checked`` steps (``change``)
+    and, with ``after``, over the last step alone (``last_change``)."""
     hp = dict(mix["optimizer"], total_steps=mix["steps"])
     R, B, S = mix["ranks"], mix["batch_per_rank"], mix["seq_len"]
     steps = checked + after
     low = variant == "bf16"
     dt = jnp.bfloat16 if low else F32
-    params = init_params(cfg, seed, dt)
+    params = arch.init_params(cfg, seed, dt)
     p0 = params
     mu = jax.tree_util.tree_map(jnp.zeros_like, params)
     nu = jax.tree_util.tree_map(jnp.zeros_like, params)
-    vg = jax.jit(jax.value_and_grad(lambda p, t: sequence_loss(p, t, cfg)))
+    vg = jax.jit(jax.value_and_grad(
+        lambda p, t: arch.sequence_loss(p, t, cfg)))
     add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
     scale = jax.jit(lambda a, s: jax.tree_util.tree_map(
         lambda x: (x * s).astype(dt), a))
@@ -325,14 +225,15 @@ def fp8_weights(params):
         donate_argnums=0)(params)
 
 
-def served_logits(params, cfg: Dict[str, Any], requests: Sequence[
-        Tuple[np.ndarray, Sequence[int]]], max_len: int,
-                  act=_same) -> List[np.ndarray]:
+def served_logits(arch: ModuleType, params, cfg: Dict[str, Any],
+                  requests: Sequence[Tuple[np.ndarray, Sequence[int]]],
+                  max_len: int, act=identity) -> List[np.ndarray]:
     """For each (prompt, served tokens): the (n, V) float32 logits at the
     positions that chose served tokens 0..n-1 (the prompt's last
     position, then each served token's), from one forward pass over the
-    prompt and the tokens, padded to ``max_len``."""
-    fwd = jax.jit(lambda p, t: sequence_logits(p, t, cfg, act=act))
+    prompt and the tokens, padded to ``max_len``, by the architecture
+    module ``arch``."""
+    fwd = jax.jit(lambda p, t: arch.sequence_logits(p, t, cfg, act=act))
     out = []
     for prompt, toks in requests:
         seq = np.zeros(max_len, np.int32)

@@ -23,7 +23,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
@@ -75,13 +74,6 @@ def enable_compile_cache(root: Path) -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def applies(metric: dict, workload: str, reported: set) -> bool:
     """Whether ``metric`` belongs in this cell's result: listed for it,
     or (with no list) moving an end-to-end metric the cell reports."""
@@ -92,16 +84,16 @@ def applies(metric: dict, workload: str, reported: set) -> bool:
 
 class Readings:
     """What a per-layer reader reads: the cell, the driver's numbers, the
-    reduced trace, the chip's peaks and the benchmark's counts."""
+    reduced trace, the chip's peaks and the benchmark's counts (the
+    module of the configuration's architecture, ``arch``)."""
 
     def __init__(self, cell, out, reduced, window, peaks, kind, chips):
-        from bench import flops
         self.cfg, self.mix = cell.cfg, cell.mix
         self.chips = chips
         self.data = out["data"]
         self.trace = reduced
         self.window = window
-        self.flops = flops
+        self.arch = cell.arch
         self._peaks, self._kind = peaks, kind
 
     def peak(self, what: str) -> float:
@@ -123,7 +115,8 @@ def fmt_check(name, value, limit, op) -> Tuple[bool, str]:
 def main(argv=None) -> int:
     args = parse(argv)
     from bench import trace_reduce
-    from bench.harness import CompileLog, Profiler, Spans, load_cell
+    from bench.harness import (CompileLog, Profiler, Spans, load_cell,
+                               load_module)
 
     cell, bench = load_cell(ROOT, args.workload, args.seed, args.seconds)
     chips = next(w["chips"] for w in bench["workloads"]

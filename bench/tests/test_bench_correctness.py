@@ -17,8 +17,9 @@ import pytest
 
 from bench import reference
 from bench.drivers import train_ddp
+from bench.harness import arch
 from bench.tests.bench_cells import run_cell
-from bench.tests.tiny_root import TINY_LIMITS, make_root, tiny_config
+from bench.tests.tiny_root import ROOT, TINY_LIMITS, make_root, tiny_config
 
 
 @pytest.fixture(scope="module")
@@ -109,8 +110,9 @@ TRAIN_MIX = {"ranks": 2, "batch_per_rank": 2, "seq_len": 32, "steps": 10**6,
 def test_training_control_and_faults_in_the_reference(variant, after):
     cfg = tiny_config("yi-6b.l1v8k", "tiny.yi", 1)
     limits = TINY_LIMITS["train_kill" if after else "train"]
-    ref = reference.train_reference(cfg, TRAIN_MIX, 7, 3, after)
-    bad = reference.train_reference(cfg, TRAIN_MIX, 7, 3, after,
+    model = arch(ROOT, cfg)
+    ref = reference.train_reference(model, cfg, TRAIN_MIX, 7, 3, after)
+    bad = reference.train_reference(model, cfg, TRAIN_MIX, 7, 3, after,
                                     variant=variant)
     nums = train_ddp.compare(bad, ref, limits["moved_floor"])
     assert any(nums[k] > limits[k] for k in nums if k in limits), nums
@@ -118,7 +120,8 @@ def test_training_control_and_faults_in_the_reference(variant, after):
 
 def test_serving_control_in_the_reference():
     cfg = tiny_config("deepseek-67b.l2", "tiny.ds", 2)
-    params = reference.init_params(cfg, 11)
+    model = arch(ROOT, cfg)
+    params = model.init_params(cfg, 11)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 256, size=n, dtype=np.int32)
                for n in (5, 17, 30)]
@@ -127,14 +130,14 @@ def test_serving_control_in_the_reference():
     for p in prompts:
         toks = []
         for _ in range(12):
-            lg = reference.served_logits(params, cfg, [(p, toks + [0])],
-                                         48)[0]
+            lg = reference.served_logits(model, params, cfg,
+                                         [(p, toks + [0])], 48)[0]
             toks.append(int(np.argmax(lg[-1])))
         sample.append((p, toks))
-    ref = reference.served_logits(params, cfg, sample, 48)
+    ref = reference.served_logits(model, params, cfg, sample, 48)
     assert reference.widest_gap(ref, [t for _, t in sample]) == 0.0
-    low = reference.served_logits(reference.fp8_weights(params), cfg,
-                                  sample, 48, act=reference.fp8_round)
+    low = reference.served_logits(model, reference.fp8_weights(params),
+                                  cfg, sample, 48, act=reference.fp8_round)
     picked = [list(np.argmax(lg, axis=-1)) for lg in low]
     gap = reference.widest_gap(ref, picked)
     assert gap > TINY_LIMITS["serve"]["logit_gap"], gap

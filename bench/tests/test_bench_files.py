@@ -9,8 +9,8 @@ import re
 
 import pytest
 
-from bench.harness import load_cell
-from bench.tests.tiny_root import ROOT
+from bench.harness import arch, load_cell
+from bench.tests.tiny_root import ARCH_FUNCTIONS, ROOT
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -33,6 +33,17 @@ def test_metric_reader_loads(metric):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert callable(mod.read) and mod.__doc__
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_architecture_module_is_complete(config):
+    c = next(c for c in BENCH["configs"] if c["name"] == config)
+    cfg = json.loads((ROOT / c["file"]).read_text())
+    cls = cfg["architectures"][0]
+    assert (ROOT / "bench" / "archs" / f"{cls}.py").is_file()
+    mod = arch(ROOT, cfg)
+    assert all(callable(getattr(mod, f, None)) for f in ARCH_FUNCTIONS), [
+        f for f in ARCH_FUNCTIONS if not callable(getattr(mod, f, None))]
 
 
 def test_configs_state_their_cuts():

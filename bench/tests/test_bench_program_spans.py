@@ -66,15 +66,15 @@ def test_idle_gaps_can_be_named_by_program_spans(root, monkeypatch,
 
 
 def test_train_d2h_is_the_ranks_gradients(root, monkeypatch, capsys):
-    from bench.harness import model_config
+    from bench.harness import arch
     from repro.models import build_model
 
     _, line, err = run_cell(root, "tiny.train", monkeypatch, capsys,
                             trace=1)
     cfg = json.loads((root / "bench" / "configs" / "tiny.yi.json")
                      .read_text())
-    shapes = jax.eval_shape(build_model(model_config(cfg)).init,
-                            jax.random.PRNGKey(0))
+    model = build_model(arch(root, cfg).model_config(cfg))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     n = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
     # two ranks, float32 gradients, plus each rank's 4-byte loss
     assert line["metrics"]["d2h_mb.train"]["value"] == pytest.approx(

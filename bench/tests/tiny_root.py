@@ -1,6 +1,7 @@
 """A checkout for tests: a copy of ``bench/`` plus tiny cells added as
 new files and new ``BENCHMARK.json`` entries only, the way a later
-change adds a configuration, a traffic mix or a per-layer metric."""
+change adds a configuration, an architecture, a traffic mix or a
+per-layer metric."""
 
 from __future__ import annotations
 
@@ -31,18 +32,60 @@ def tiny_config(src: str, name: str, layers: int) -> dict:
     return cfg
 
 
+#: an architecture module's interface: the program's ``ModelConfig``, the
+#: float32 reference, and the counts of operations and bytes
+ARCH_FUNCTIONS = ("model_config", "init_params", "sequence_logits",
+                  "sequence_loss", "train_flops_per_token", "prefill_flops",
+                  "decode_flops", "decode_bytes")
+
+#: an architecture added as one file: the Llama module's functions under
+#: another class name, each call counted in ``CALLS``
+TINY_ARCH = '''"""TinyForCausalLM: the Llama functions, each call counted."""
+
+import collections
+from pathlib import Path
+
+from bench.harness import load_module
+
+_llama = load_module(Path(__file__).with_name("LlamaForCausalLM.py"),
+                     "tiny_llama")
+CALLS = collections.Counter()
+
+
+def _counted(name):
+    inner = getattr(_llama, name)
+
+    def counted(*args, **kwargs):
+        CALLS[name] += 1
+        return inner(*args, **kwargs)
+    return counted
+
+
+for _name in %r:
+    globals()[_name] = _counted(_name)
+''' % (ARCH_FUNCTIONS,)
+
+
 def make_root(tmp: Path) -> Path:
-    """``tmp`` as a checkout holding the benchmark and four tiny cells:
+    """``tmp`` as a checkout holding the benchmark and seven tiny cells:
     ``tiny.train``, ``tiny.train_kill``, ``tiny.chat`` and
-    ``tiny.chat_kill``, and one added per-layer metric,
-    ``tiny_steps.train``."""
+    ``tiny.chat_kill``; ``tiny.arch.train`` and ``tiny.arch.chat`` on
+    the architecture ``TinyForCausalLM``, added as one file;
+    ``tiny.noarch.chat``, whose architecture has no module; and one
+    added per-layer metric, ``tiny_steps.train``."""
     shutil.copytree(BENCH, tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     b = tmp / "bench"
-    for src, name, layers in (("yi-6b.l1v8k", "tiny.yi", 1),
-                              ("deepseek-67b.l2", "tiny.ds", 2)):
-        (b / "configs" / f"{name}.json").write_text(
-            json.dumps(tiny_config(src, name, layers)))
+    (b / "archs" / "TinyForCausalLM.py").write_text(TINY_ARCH)
+    for src, name, layers, cls in (
+            ("yi-6b.l1v8k", "tiny.yi", 1, None),
+            ("deepseek-67b.l2", "tiny.ds", 2, None),
+            ("yi-6b.l1v8k", "tiny.arch", 1, "TinyForCausalLM"),
+            ("yi-6b.l1v8k", "tiny.noarch", 1, "NoSuchForCausalLM")):
+        cfg = tiny_config(src, name, layers)
+        if cls:
+            cfg["architectures"] = [cls]
+        (b / "configs" / f"{name}.json").write_text(json.dumps(cfg))
     for mix in ("ddp_healthy", "ddp_nic_kill"):
         m = json.loads((b / "traffic" / f"{mix}.json").read_text())
         m.update(batch_per_rank=2, seq_len=32, max_chunk_bytes=1 << 14)
@@ -60,15 +103,18 @@ def make_root(tmp: Path) -> Path:
         'def read(r):\n    return float(r.data["steps"])\n')
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     bench["configs"] += [
-        {"name": "tiny.yi", "source": "tiny", "reduced": [], "why": "tests",
-         "file": "bench/configs/tiny.yi.json"},
-        {"name": "tiny.ds", "source": "tiny", "reduced": [], "why": "tests",
-         "file": "bench/configs/tiny.ds.json"}]
+        {"name": name, "source": "tiny", "reduced": [], "why": "tests",
+         "file": f"bench/configs/{name}.json"}
+        for name in ("tiny.yi", "tiny.ds", "tiny.arch", "tiny.noarch")]
     cells = {"tiny.train": ("tiny.yi", "tiny_ddp_healthy", "train"),
              "tiny.train_kill": ("tiny.yi", "tiny_ddp_nic_kill", "train"),
              "tiny.chat": ("tiny.ds", "tiny_chat_open_loop", "serve"),
              "tiny.chat_kill": ("tiny.ds", "tiny_chat_open_loop_nic_kill",
-                                "serve")}
+                                "serve"),
+             "tiny.arch.train": ("tiny.arch", "tiny_ddp_healthy", "train"),
+             "tiny.arch.chat": ("tiny.arch", "tiny_chat_open_loop", "serve"),
+             "tiny.noarch.chat": ("tiny.noarch", "tiny_chat_open_loop",
+                                  "serve")}
     for name, (cfg, mix, kind) in cells.items():
         bench["workloads"].append({"name": name, "config": cfg,
                                    "traffic": mix, "chips": 1, "why": "tests"})
