@@ -185,8 +185,8 @@ def hooked_rows(fast: bool = True):
 
 def serving_rows(fast: bool = True):
     """Fault-tolerant TP serving smoke: the continuous-batching serving
-    workload (per-step logits/activation gathers + MoE all-to-alls,
-    request-level invariants) over the scenario subset the ISSUE-6
+    workload (per-step logits/activation gathers, request-level
+    invariants) over the scenario subset the ISSUE-6
     acceptance names — including the unmaskable double outage, which
     must fail requests loudly rather than corrupt tokens. Runs on both
     datapaths (the workload rides JcclWorld, which honours ``fast``)."""

@@ -7,9 +7,9 @@ Run:  PYTHONPATH=src python examples/serve_decode.py [--arch yi-6b]
 configs are exercised by the dry-run.)
 
 ``--tp`` shards the engine across a 2-rank JCCL world (per-step logits
-and K/V all-gathers, MoE all-to-alls for moe archs) and checks the
-output is byte-identical to the single-host run — the fabric moves
-bytes, it never changes them. See docs/serving.md.
+and K/V all-gathers) and checks the output is byte-identical to the
+single-host run — the fabric moves bytes, it never changes them. See
+docs/serving.md.
 """
 
 import argparse

@@ -24,11 +24,12 @@ ARCH_MODULES = {
     "kimi-k2-1t-a32b": "kimi_k2_1t",
     "llama4-maverick-400b-a17b": "llama4_maverick",
     "musicgen-medium": "musicgen_medium",
+    "step3": "step3",
     # the paper's own evaluation model (not part of the assigned 10)
     "gpt2-124m": "gpt2_124m",
 }
 
-ASSIGNED = [a for a in ARCH_MODULES if a != "gpt2-124m"]
+ASSIGNED = [a for a in ARCH_MODULES if a not in ("gpt2-124m", "step3")]
 
 # archs whose decode state is sub-quadratic (run long_500k)
 SUBQUADRATIC = {"rwkv6-3b", "zamba2-1.2b"}

@@ -7,7 +7,7 @@ def config(**overrides) -> ModelConfig:
     base = dict(
         name="llama4-maverick-400b-a17b", family="moe", n_layers=48,
         d_model=5120, n_heads=40, n_kv_heads=8, d_ff=8192, vocab=202048,
-        n_experts=128, top_k=1, capacity_factor=1.25,
+        n_experts=128, top_k=1,
     )
     base.update(overrides)
     return ModelConfig(**base)
@@ -17,7 +17,7 @@ def smoke_config(**overrides) -> ModelConfig:
     base = dict(
         name="llama4-maverick-smoke", family="moe", n_layers=2, d_model=128,
         n_heads=8, n_kv_heads=2, d_ff=128, vocab=512,
-        n_experts=4, top_k=1, capacity_factor=1.5,
+        n_experts=4, top_k=1,
     )
     base.update(overrides)
     return ModelConfig(**base)

@@ -1,4 +1,6 @@
-"""Attention: GQA with RoPE — blockwise (flash-style) training/prefill path
+"""Attention: GQA with RoPE, optionally with a low-rank query
+(``cfg.q_rank``: one product for the query's code and K/V, a norm, then
+the query heads) — blockwise (flash-style) training/prefill path
 with a memory-proper flash BACKWARD, single-token decode over a KV cache,
 and cross-attention (VLM).
 
@@ -26,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import (ModelConfig, apply_rope, constrain, dp_axis_names,
-                     model_axis_size)
+                     model_axis_size, rms_norm)
 
 NEG_INF = -1e30
 
@@ -231,6 +233,19 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     return out.reshape(B, H, hd).astype(q.dtype)
 
 
+def _low_rank_qkv(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> Tuple:
+    """The low-rank query: one product gives the query's rank-r code and
+    the K and V heads, the code is normed and lifted to the H query
+    heads. x (B, S, D) -> q (B, S, H, hd), k and v (B, S, KV, hd)."""
+    B, S, _ = x.shape
+    r, KVh, hd = cfg.q_rank, cfg.n_kv_heads, cfg.hd
+    qkv = jnp.einsum("bsd,de->bse", x, p["wqkv"].astype(x.dtype))
+    q_a, k, v = jnp.split(qkv, [r, r + KVh * hd], axis=-1)
+    q = jnp.einsum("bsr,rhk->bshk", rms_norm(q_a, p["q_norm"], cfg.norm_eps),
+                   p["wq"].astype(x.dtype))
+    return q, k.reshape(B, S, KVh, hd), v.reshape(B, S, KVh, hd)
+
+
 def attention_sublayer(x: jnp.ndarray, p: dict, cfg: ModelConfig,
                        positions: jnp.ndarray,
                        cache: Optional[dict] = None,
@@ -246,10 +261,14 @@ def attention_sublayer(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     dp = dp_axis_names()
     tp = model_axis_size()
     heads_shard = tp > 1 and H % tp == 0
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    if cfg.q_rank and kv_override is None:
+        q, k, v = _low_rank_qkv(x, p, cfg)
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+        if kv_override is None:
+            k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
+            v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
     if kv_override is None:
-        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     else:
@@ -319,6 +338,12 @@ def init_attention(key, cfg: ModelConfig, dtype) -> dict:
     from .common import init_dense, split_keys
     D, H, KVh, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     k1, k2, k3, k4 = split_keys(key, 4)
+    if cfg.q_rank:
+        r = cfg.q_rank
+        return {"wqkv": init_dense(k1, (D, r + 2 * KVh * hd), dtype=dtype),
+                "q_norm": jnp.ones((r,), dtype=dtype),
+                "wq": init_dense(k2, (r, H, hd), dtype=dtype),
+                "wo": init_dense(k4, (H, hd, D), dtype=dtype)}
     return {
         "wq": init_dense(k1, (D, H, hd), dtype=dtype),
         "wk": init_dense(k2, (D, KVh, hd), dtype=dtype),

@@ -1,4 +1,4 @@
-"""Layer blocks: SwiGLU MLP, MoE (sort-based dropping dispatch, EP-shardable),
+"""Layer blocks: SwiGLU MLP, a dropless MoE over a held range of experts,
 RWKV6 time/channel mix, Mamba2 SSD — pure JAX, kernel-routable."""
 
 from __future__ import annotations
@@ -64,71 +64,62 @@ def init_mlp(key, d: int, f: int, dtype) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# MoE: top-k routing with capacity dropping, sort-based dispatch.
-# Experts dimension shards over the "model" mesh axis (EP); the scatter /
-# gather indices stay per-token so the partitioner inserts all-to-alls for
-# the (E, C, D) expert buffers — the MoE dispatch traffic of the paper's
-# Table 1 (bulk writes; SHIFT-safe).
+# MoE: a dropless expert layer over the held range of experts. The router
+# scores all ``n_experts``; the (token, expert) pairs that fall on the
+# ``expert_range`` this model holds are sorted by expert and computed by
+# one grouped product per matrix, with no capacity and no dropped pair.
+# With every expert held this is the whole layer; with a range it is one
+# chip's share of an expert-parallel layer, whose other shares would be
+# added by the chips that hold them.
 # ---------------------------------------------------------------------------
 
 
-def moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
+def moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig):
+    """x (B, S, D) -> (the held experts' part of the layer plus the
+    shared expert, (count,) rows routed to each held expert)."""
     B, S, D = x.shape
-    E, K = cfg.n_experts, cfg.top_k
+    K = cfg.top_k
+    first, count = cfg.expert_range
     G = B * S
     xt = x.reshape(G, D)
-    logits = jnp.einsum("gd,de->ge", xt, p["router"].astype(x.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, idx = jax.lax.top_k(probs, K)                    # (G,K)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    logits = jnp.einsum("gd,de->ge", xt, p["router"].astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)  # (G,K)
+    if cfg.renormalize_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    dp = dp_axis_names()
-    C = max(int(G * K * cfg.capacity_factor / E), 1)
-    flat_e = idx.reshape(-1)                                # (G*K,)
-    order = jnp.argsort(flat_e)                             # stable
-    sorted_e = flat_e[order]
-    # rank within each expert run
-    pos = jnp.arange(G * K) - jnp.searchsorted(sorted_e, sorted_e,
-                                               side="left")
-    keep = pos < C
-    dest = jnp.where(keep, sorted_e * C + pos, E * C)       # drop slot at end
-    tok = order // K
-    # NB (§Perf hillclimb #1, refuted hypothesis): DP-constraining the
-    # gathered (G*K, D) tokens forced extra reshards (745 -> 1310 GB/dev
-    # on kimi-k2 train_4k); only the expert-parallel buffer constraints
-    # below survive measurement.
-    buf = jnp.zeros((E * C + 1, D), dtype=x.dtype)
-    buf = buf.at[dest].set(xt[tok])
-    # NB (§Perf hillclimb #1, second refuted hypothesis): forcing the
-    # (E, C, D) buffers to P("model", None, None) ALSO regressed
-    # (745 -> 1985 GB/dev) — GSPMD's own scatter sharding beats both
-    # manual placements here. Expert weights stay EP-sharded via the
-    # parameter specs; dispatch sharding is left to the partitioner.
-    ebuf = buf[:E * C].reshape(E, C, D)
-
+    local = idx.reshape(-1) - first                         # (G*K,)
+    held = (local >= 0) & (local < count)
+    # pairs on other chips' experts sort last, past every held group
+    order = jnp.argsort(jnp.where(held, local, count))      # stable
+    rows = jnp.bincount(jnp.where(held, local, count),
+                        length=count + 1)[:count].astype(jnp.int32)
+    xs = xt[order // K]                                     # (G*K, D)
     a = act_fn(cfg.act)
-    h = a(jnp.einsum("ecd,edf->ecf", ebuf, p["w_gate"].astype(x.dtype)))
-    h = h * jnp.einsum("ecd,edf->ecf", ebuf, p["w_up"].astype(x.dtype))
-    eout = jnp.einsum("ecf,efd->ecd", h, p["w_down"].astype(x.dtype))
-
-    flat_out = jnp.concatenate(
-        [eout.reshape(E * C, D), jnp.zeros((1, D), eout.dtype)], axis=0)
-    picked = flat_out[dest]                                 # (G*K, D) sorted
-    unsorted = jnp.zeros((G * K, D), dtype=eout.dtype).at[order].set(picked)
-    yk = unsorted.reshape(G, K, D)
-    y = jnp.einsum("gkd,gk->gd", yk, gates.astype(eout.dtype))
+    h = a(jax.lax.ragged_dot(xs, p["w_gate"].astype(x.dtype), rows)) * \
+        jax.lax.ragged_dot(xs, p["w_up"].astype(x.dtype), rows)
+    out = jax.lax.ragged_dot(h, p["w_down"].astype(x.dtype), rows)
+    # back to (token, k) order; rows past the held groups are zeroed
+    out = jnp.where(held[order][:, None], out, 0)[jnp.argsort(order)]
+    gw = jnp.where(held, gates.reshape(-1), 0.0).reshape(G, K)
+    y = jnp.einsum("gkd,gk->gd", out.reshape(G, K, D), gw.astype(out.dtype))
     if cfg.shared_expert_ff:
         y = y + mlp(x, p["shared"], cfg).reshape(G, D)
-    return y.reshape(B, S, D).astype(x.dtype)
+    return y.reshape(B, S, D).astype(x.dtype), rows
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
-    k1, k2, k3, k4, k5 = split_keys(key, 5)
-    p = {"router": init_dense(k1, (d, E), dtype=dtype),
-         "w_gate": init_dense(k2, (E, d, f), in_axis=1, dtype=dtype),
-         "w_up": init_dense(k3, (E, d, f), in_axis=1, dtype=dtype),
-         "w_down": init_dense(k4, (E, f, d), in_axis=1, dtype=dtype)}
+    """The router over all ``n_experts`` and the held experts' weights:
+    expert e's are drawn from the e-th of ``n_experts`` keys, so a held
+    range holds the same numbers as that range of the whole layer."""
+    d, f = cfg.d_model, cfg.d_ff
+    first, count = cfg.expert_range
+    k1, k2, _, _, k5 = split_keys(key, 5)
+    ekeys = jax.random.split(k2, cfg.n_experts)[first:first + count]
+    experts = jax.vmap(lambda k: init_mlp(k, d, f, dtype))(ekeys)
+    p = {"router": init_dense(k1, (d, cfg.n_experts), dtype=dtype),
+         "w_gate": experts["w_gate"], "w_up": experts["w_up"],
+         "w_down": experts["w_down"]}
     if cfg.shared_expert_ff:
         p["shared"] = init_mlp(k5, d, cfg.shared_expert_ff, dtype)
     return p

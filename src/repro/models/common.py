@@ -32,12 +32,15 @@ class ModelConfig:
     norm_eps: float = 1e-5
     act: str = "silu"            # silu (swiglu) | gelu
     tie_embeddings: bool = False
+    q_rank: int = 0              # low-rank query bottleneck width (0: none)
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0           # the router's width
     top_k: int = 0
-    capacity_factor: float = 1.25
+    held_experts: Optional[Tuple[int, int]] = None  # (first, count); None: all
+    renormalize_topk: bool = True
     shared_expert_ff: int = 0
-    router_jitter: float = 0.0
+    n_dense_layers: int = 0      # leading dense layers ahead of the MoE stack
+    dense_ff: int = 0            # their SwiGLU width
     # --- SSM / hybrid ---
     ssm_state: int = 0           # mamba2 state size N
     ssm_head_dim: int = 64
@@ -64,26 +67,42 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
+    @property
+    def expert_range(self) -> Tuple[int, int]:
+        """(first, count) of the experts this model holds."""
+        return self.held_experts or (0, self.n_experts)
+
+    def _attn_params(self) -> int:
+        d, hd, H, KV, r = (self.d_model, self.hd, self.n_heads,
+                           self.n_kv_heads, self.q_rank)
+        q = d * r + r + r * H * hd if r else d * H * hd
+        return q + 2 * d * KV * hd + H * hd * d
+
+    def _stack_params(self, experts: int) -> int:
+        """Layers, with their two norms, when each MoE layer computes
+        ``experts`` experts per token (every held one for a count of
+        parameters, ``top_k`` for the active ones)."""
+        d, L = self.d_model, self.n_layers
+        if self.family != "moe":
+            return L * (self._attn_params() + 3 * d * self.d_ff + 2 * d)
+        moe = experts * 3 * d * self.d_ff + d * self.n_experts \
+            + 3 * d * self.shared_expert_ff
+        nd = self.n_dense_layers
+        return L * (self._attn_params() + 2 * d) \
+            + nd * 3 * d * self.dense_ff + (L - nd) * moe
+
     def param_count(self) -> int:
-        """Total parameters (for 6ND MODEL_FLOPS accounting)."""
+        """Total parameters (for 6ND MODEL_FLOPS accounting); a MoE model
+        counts the experts it holds."""
         d, f, V, L = self.d_model, self.d_ff, self.vocab, self.n_layers
-        hd = self.hd
         emb = V * d * (1 if self.tie_embeddings else 2)
         if self.family in ("dense", "vlm", "audio", "moe"):
-            attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
-                + (self.n_heads * hd) * d
-            if self.family == "moe":
-                ff = self.n_experts * (3 * d * f) + d * self.n_experts
-                if self.shared_expert_ff:
-                    ff += 3 * d * self.shared_expert_ff
-            else:
-                ff = 3 * d * f
-            per_layer = attn + ff + 2 * d
             extra = 0
             if self.family == "vlm" and self.cross_attn_every:
                 n_cross = L // self.cross_attn_every
-                extra = n_cross * (attn + 2 * d)
-            return emb + L * per_layer + extra + d
+                extra = n_cross * (self._attn_params() + 2 * d)
+            return emb + self._stack_params(self.expert_range[1]) \
+                + extra + d
         if self.family == "rwkv6":
             # time mix: wr/wk/wv/wg/ww + wo = 6 d^2; channel: w_k/w_v (2df)
             # + w_r (d^2); small vectors
@@ -106,15 +125,8 @@ class ModelConfig:
         """Active parameters per token (MoE: top_k of n_experts)."""
         if self.family != "moe" or not self.n_experts:
             return self.param_count()
-        d, f, L = self.d_model, self.d_ff, self.n_layers
-        hd = self.hd
-        attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
-            + (self.n_heads * hd) * d
-        ff_active = self.top_k * (3 * d * f) + d * self.n_experts
-        if self.shared_expert_ff:
-            ff_active += 3 * d * self.shared_expert_ff
-        emb = self.vocab * d * (1 if self.tie_embeddings else 2)
-        return emb + L * (attn + ff_active + 2 * d) + d
+        emb = self.vocab * self.d_model * (1 if self.tie_embeddings else 2)
+        return emb + self._stack_params(self.top_k) + self.d_model
 
 
 # ---------------------------------------------------------------------------

@@ -78,18 +78,24 @@ class LM:
             params["lm_head"] = init_dense(keys[1], (D, V), dtype=dt)
 
         if cfg.family in ("dense", "audio", "moe"):
-            def init_block(k):
+            def init_block(k, moe, d_ff):
                 k1, k2 = jax.random.split(k)
                 blk = {"attn": A.init_attention(k1, cfg, dt),
                        "ln1": jnp.ones((D,), dtype=dt),
                        "ln2": jnp.ones((D,), dtype=dt)}
-                if cfg.family == "moe":
+                if moe:
                     blk["moe"] = BL.init_moe(k2, cfg, dt)
                 else:
-                    blk["mlp"] = BL.init_mlp(k2, D, cfg.d_ff, dt)
+                    blk["mlp"] = BL.init_mlp(k2, D, d_ff, dt)
                 return blk
-            bkeys = jnp.stack(split_keys(keys[2], cfg.n_layers))
-            params["blocks"] = jax.vmap(init_block)(bkeys)
+            nd = cfg.n_dense_layers
+            bkeys = jnp.stack(split_keys(keys[2], cfg.n_layers - nd))
+            params["blocks"] = jax.vmap(functools.partial(
+                init_block, moe=cfg.family == "moe", d_ff=cfg.d_ff))(bkeys)
+            if nd:
+                dkeys = jnp.stack(split_keys(keys[3], nd))
+                params["dense_blocks"] = jax.vmap(functools.partial(
+                    init_block, moe=False, d_ff=cfg.dense_ff))(dkeys)
 
         elif cfg.family == "vlm":
             every = cfg.cross_attn_every
@@ -149,18 +155,31 @@ class LM:
     # ------------------------------------------------------------------
     # forward pieces
     # ------------------------------------------------------------------
-    def _dense_block(self, x, blk, positions, cache=None, aux=None):
+    def _stacks(self, params):
+        """Each stacked group of decoder layers with its first and end
+        layer, in forward order: a MoE model's leading dense layers, then
+        the rest."""
+        L, nd = self.cfg.n_layers, self.cfg.n_dense_layers
+        if not nd:
+            return [(params["blocks"], 0, L)]
+        return [(params["dense_blocks"], 0, nd), (params["blocks"], nd, L)]
+
+    def _dense_block(self, x, blk, positions, cache=None):
+        """One decoder layer -> (x, new K/V, rows routed to each held
+        expert, or None for a dense MLP)."""
         cfg = self.cfg
         h, kv = A.attention_sublayer(
             rms_norm(x, blk["ln1"], cfg.norm_eps), blk["attn"], cfg,
             positions, cache=cache)
         x = x + h
         y = rms_norm(x, blk["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            x = x + BL.moe_mlp(y, blk["moe"], cfg)
+        rows = None
+        if "moe" in blk:
+            out, rows = BL.moe_mlp(y, blk["moe"], cfg)
+            x = x + out
         else:
             x = x + BL.mlp(y, blk["mlp"], cfg)
-        return x, kv
+        return x, kv, rows
 
     def _cross_block(self, x, blk, img_kv):
         cfg = self.cfg
@@ -208,10 +227,11 @@ class LM:
 
         if cfg.family in ("dense", "audio", "moe"):
             def block(x, blk):
-                out, _ = self._dense_block(x, blk, positions)
+                out, _, _ = self._dense_block(x, blk, positions)
                 return out, None
             block = _maybe_remat(block, cfg)
-            x, _ = _scan_blocks(block, x, params["blocks"], cfg.scan_layers)
+            for blocks, _, _ in self._stacks(params):
+                x, _ = _scan_blocks(block, x, blocks, cfg.scan_layers)
 
         elif cfg.family == "vlm":
             if img_embeds is None:
@@ -225,7 +245,7 @@ class LM:
                 x = self._cross_block(x, cross, img_kv)
 
                 def sblock(x, blk):
-                    out, _ = self._dense_block(x, blk, positions)
+                    out, _, _ = self._dense_block(x, blk, positions)
                     return out, None
                 x, _ = jax.lax.scan(sblock, x, selfs)
                 return x, None
@@ -311,9 +331,15 @@ class LM:
         cdt = cfg.dtype
         if cfg.family in ("dense", "audio", "moe"):
             L = cfg.n_layers
-            return {"k": jnp.zeros((L, B, max_len, KV, hd), cdt),
-                    "v": jnp.zeros((L, B, max_len, KV, hd), cdt),
-                    "len": jnp.zeros((), jnp.int32)}
+            cache = {"k": jnp.zeros((L, B, max_len, KV, hd), cdt),
+                     "v": jnp.zeros((L, B, max_len, KV, hd), cdt),
+                     "len": jnp.zeros((), jnp.int32)}
+            if cfg.family == "moe":
+                # rows the last decode step routed to each held expert,
+                # per MoE layer
+                cache["expert_rows"] = jnp.zeros(
+                    (L - cfg.n_dense_layers, cfg.expert_range[1]), jnp.int32)
+            return cache
         if cfg.family == "vlm":
             n_groups = cfg.n_layers // cfg.cross_attn_every
             n_self = cfg.cross_attn_every - 1
@@ -372,13 +398,24 @@ class LM:
         if cfg.family in ("dense", "audio", "moe"):
             def block(x, xs):
                 blk, kc, vc = xs
-                out, (k_new, v_new) = self._dense_block(
+                out, kv, rows = self._dense_block(
                     x, blk, pos, cache={"k": kc, "v": vc, "len": cache["len"]})
-                return out, (k_new, v_new)
-            x, (k_all, v_all) = _scan_blocks(
-                block, x, (params["blocks"], cache["k"], cache["v"]),
-                cfg.scan_layers)
-            new_cache = {"k": k_all, "v": v_all, "len": cache["len"] + 1}
+                return out, (kv, rows)
+            stacks = self._stacks(params)
+            ks, vs = [], []
+            new_cache = {"len": cache["len"] + 1}
+            for blocks, lo, hi in stacks:
+                kc, vc = cache["k"], cache["v"]
+                if len(stacks) > 1:
+                    kc, vc = kc[lo:hi], vc[lo:hi]
+                x, ((k_all, v_all), rows) = _scan_blocks(
+                    block, x, (blocks, kc, vc), cfg.scan_layers)
+                ks.append(k_all)
+                vs.append(v_all)
+                if rows is not None:
+                    new_cache["expert_rows"] = rows
+            new_cache["k"] = ks[0] if len(ks) == 1 else jnp.concatenate(ks)
+            new_cache["v"] = vs[0] if len(vs) == 1 else jnp.concatenate(vs)
 
         elif cfg.family == "vlm":
             def group(x, xs):
@@ -392,7 +429,7 @@ class LM:
 
                 def sblock(x, xs2):
                     blk, kc2, vc2 = xs2
-                    out, (kn, vn) = self._dense_block(
+                    out, (kn, vn), _ = self._dense_block(
                         x, blk, pos, cache={"k": kc2, "v": vc2,
                                             "len": cache["len"]})
                     return out, (kn, vn)
@@ -493,10 +530,16 @@ class LM:
 
         if cfg.family in ("dense", "audio", "moe"):
             def block(x, blk):
-                out, (k, v) = self._dense_block(x, blk, positions)
+                out, (k, v), _ = self._dense_block(x, blk, positions)
                 return out, (k, v)
-            x, (ks, vs) = _scan_blocks(_maybe_remat(block, cfg), x,
-                                       params["blocks"], cfg.scan_layers)
+            ks, vs = [], []
+            for blocks, _, _ in self._stacks(params):
+                x, (k, v) = _scan_blocks(_maybe_remat(block, cfg), x,
+                                         blocks, cfg.scan_layers)
+                ks.append(k)
+                vs.append(v)
+            ks = ks[0] if len(ks) == 1 else jnp.concatenate(ks)
+            vs = vs[0] if len(vs) == 1 else jnp.concatenate(vs)
             cache["k"] = pad_kv(ks).astype(cfg.dtype)
             cache["v"] = pad_kv(vs).astype(cfg.dtype)
 
@@ -512,7 +555,7 @@ class LM:
                 x = self._cross_block(x, cross, (img_k, img_v))
 
                 def sblock(x, blk):
-                    out, (k, v) = self._dense_block(x, blk, positions)
+                    out, (k, v), _ = self._dense_block(x, blk, positions)
                     return out, (k, v)
                 x, (ks, vs) = jax.lax.scan(sblock, x, selfs)
                 return x, (ks, vs, img_k, img_v)

@@ -33,8 +33,7 @@ Workloads, in increasing weight:
   (the overlapped-DDP smoke; a run that never overlaps is a violation).
 * ``serving`` — continuous-batching tensor-parallel inference
   (``repro.serving.tp`` + ``repro.serving.scheduler``) on the fabric:
-  per-step logits/activation all-gathers and MoE all-to-alls under the
-  fault timeline, with request-level invariants (no dropped requests,
+  per-step logits/activation all-gathers under the fault timeline, with request-level invariants (no dropped requests,
   no duplicated/truncated/corrupted tokens — byte-exact against the
   single-host reference run).
 * ``mixed`` — all three latency classes live at once (DESIGN.md §10):
@@ -861,9 +860,7 @@ def run_serving(scenario: Scenario, seed: int = 0, n_requests: int = 4,
     must be byte-identical to the single-host reference (sampling runs
     on fabric-reconstructed logits, so corruption IS observable as a
     wrong token). Filler waves must complete but are not token-compared:
-    MoE expert-capacity contention couples rows within a batch, so only
-    the wave that replays the reference's exact schedule is
-    byte-comparable.
+    the reference run replays only the first wave's schedule.
     """
     from repro.collectives import CollectiveError, build_world
     from repro.serving import RequestScheduler, TPServeEngine

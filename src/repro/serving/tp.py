@@ -16,10 +16,11 @@ fault can therefore corrupt — is the data movement between the shards:
   appends to the cache are gathered layer-by-layer (one concurrent work
   per layer, mirroring megatron-style per-layer activation sync) and
   byte-verified against the locally computed rows.
-* **MoE expert all-to-alls** — for ``family == "moe"`` models the step's
-  activation bytes take a dispatch + combine ``all_to_all`` round trip
-  (every ordered rank pair carries real payload) and must come back
-  byte-identical.
+
+A MoE model's expert layer computes the experts it holds
+(``ModelConfig.held_experts``) with no exchange: the all-to-all of a
+layer split over chips needs a rank per chip, which the ranks here,
+sharing one device, are not.
 
 All of a step's works are issued before any is waited on, so a scenario
 fault lands while several collectives are in flight and SHIFT's
@@ -117,48 +118,19 @@ class TPServeEngine:
         return {f"kv{layer}": _bytes_of(rows[layer])
                 for layer in range(rows.shape[0])}
 
-    def _expert_dispatch(self, flat: np.ndarray):
-        """Launch the MoE expert-dispatch all-to-all carrying the step's
-        activation bytes: every rank sends row j of the byte matrix to
-        rank j, so each ordered rank pair moves real payload."""
-        n = self.world.n_ranks
-        width = max(1, -(-flat.size // n))
-        mat = np.zeros((n, width), dtype=np.uint8)
-        mat.reshape(-1)[:flat.size] = flat
-        mats = [mat.copy() for _ in range(n)]
-        return mat, self.world.all_to_all_async(
-            mats, priority="latency_critical")
-
-    def _expert_combine(self, mat: np.ndarray, dispatch) -> None:
-        """Verify the dispatch leg, then run the combine leg (the return
-        all-to-all) and verify the round trip restored every byte."""
-        outs = dispatch.result()
-        n = self.world.n_ranks
-        for j in range(n):
-            for i in range(n):
-                if not np.array_equal(outs[j][i], mat[j]):
-                    self.reconstruction_mismatches += 1
-        combine = self.world.all_to_all_async([o.copy() for o in outs],
-                                              priority="latency_critical")
-        self.world.wait_all([combine], timeout=self.timeout)
-        for back in combine.result():
-            if not np.array_equal(back, mat):
-                self.reconstruction_mismatches += 1
-
     def _sync(self, logits, cache=None, prev_len=None):
         """One step's fabric synchronization point.
 
         Issues EVERY work of the step before waiting on any of them —
-        the logits all-gather, one K/V-row all-gather per layer, and
-        (MoE) the expert dispatch — so faults land mid-overlap. All of
-        a step's works carry the ``latency_critical`` class: a decode
-        step is a tail-latency SLO, so its chunks overtake queued bulk
-        gradient buckets and background checkpoint streams at the
-        per-(rank, peer) dispatch queues (DESIGN.md §10). It then
-        waits the batch, byte-verifies each reconstruction against the
-        local truth, and runs the MoE combine leg. Returns the logits
-        rebuilt FROM FABRIC BYTES as a device array: the sampler only
-        ever sees what the network delivered.
+        the logits all-gather and one K/V-row all-gather per layer — so
+        faults land mid-overlap. All of a step's works carry the
+        ``latency_critical`` class: a decode step is a tail-latency SLO,
+        so its chunks overtake queued bulk gradient buckets and
+        background checkpoint streams at the per-(rank, peer) dispatch
+        queues (DESIGN.md §10). It then waits the batch and
+        byte-verifies each reconstruction against the local truth.
+        Returns the logits rebuilt FROM FABRIC BYTES as a device array:
+        the sampler only ever sees what the network delivered.
         """
         self.sync_rounds += 1
         if self.world is None:
@@ -173,18 +145,12 @@ class TPServeEngine:
         works = {name: self.world.gather_replicated_async(
                      b, priority="latency_critical")
                  for name, b in payloads.items()}
-        moe = None
-        if self.model.cfg.family == "moe" and "kv0" in payloads:
-            moe = self._expert_dispatch(payloads["kv0"])
-        batch = list(works.values()) + ([moe[1]] if moe else [])
-        self.world.wait_all(batch, timeout=self.timeout)
+        self.world.wait_all(list(works.values()), timeout=self.timeout)
         with tracing.span("tp.verify"):
             for name, b in payloads.items():
                 for rec in works[name].result():
                     if not np.array_equal(rec, b):
                         self.reconstruction_mismatches += 1
-            if moe is not None:
-                self._expert_combine(*moe)
         rec0 = works["logits"].result()[0]
         tracing.add(h2d_bytes=rec0.nbytes)
         return jnp.asarray(rec0.view(lg.dtype).reshape(lg.shape))
@@ -292,6 +258,19 @@ class TPServeEngine:
             logits, self._cache = self._local._decode(
                 self.params, self._cache, jnp.asarray(feed)[:, None])
         rec = self._sync(logits, self._cache, prev_len)
+        if "expert_rows" in self._cache:
+            self._expert_counts()
         toks = np.asarray(jnp.argmax(rec[:, -1], axis=-1))
         tracing.add(d2h_bytes=toks.nbytes)
         return toks.astype(np.int32)
+
+    def _expert_counts(self) -> None:
+        """The rows the last decode step routed to each held expert of
+        each MoE layer (free slots' rows too), read once its logits have
+        reached the host: a few bytes and no wait for the device."""
+        with tracing.span("tp.expert_counts"):
+            rows = np.asarray(self._cache["expert_rows"])
+            tracing.add(d2h_bytes=rows.nbytes,
+                        experts_hit=int(np.count_nonzero(rows)),
+                        experts_run=int(rows.size),
+                        expert_rows=int(rows.sum()))

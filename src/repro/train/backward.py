@@ -20,7 +20,8 @@ leaf's flat range is split into per-layer rows and row ``i`` is
 assigned to the segment that runs layer ``i``'s backward. Per model
 family:
 
-* dense / moe / audio / rwkv6: ``blocks`` (leading dim = n_layers);
+* dense / moe / audio / rwkv6: ``blocks`` (leading dim = n_layers; a
+  MoE stack's leading dense layers are ``dense_blocks``, ahead of it);
 * vlm: ``cross_blocks`` (G, ...) and ``self_blocks`` (G, S, ...) scan
   together — row ``g`` of both lands in the same segment;
 * hybrid: ``groups`` (G, S, ...) then ``rem`` (R, ...) in forward
@@ -51,7 +52,7 @@ HEAD_KEYS = ("final_norm", "lm_head")
 #: later in the forward pass produce their gradients first); keys in
 #: the same tuple scan together and share row segments
 STACKED_BACKWARD_ORDER = (("rem",), ("groups",), ("blocks",),
-                          ("cross_blocks", "self_blocks"))
+                          ("dense_blocks",), ("cross_blocks", "self_blocks"))
 #: top-level param entries produced by the LAST backward segment
 EMBED_KEYS = ("embed",)
 

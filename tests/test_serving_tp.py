@@ -20,7 +20,7 @@ MAX_LEN = 32
 @pytest.fixture(scope="module", params=["dense", "moe"])
 def setup(request):
     """(model, params, shared local engine, prompts) per family — moe
-    exercises the expert all-to-all path, dense the pure-gather path."""
+    exercises the dropless expert layer, dense the plain MLP."""
     cfg = (gpt2_124m if request.param == "dense"
            else llama4_maverick).smoke_config()
     model = build_model(cfg)
@@ -57,14 +57,14 @@ def test_tp_generate_byte_identical_greedy_and_sampled(setup):
 
 def test_tp_sync_overlaps_per_layer_gathers(setup):
     """Every decode step issues the logits gather + one gather per layer
-    (+ the MoE dispatch) before waiting: the world must observe them
-    live simultaneously or the per-layer overlap claim is vacuous."""
+    before waiting: the world must observe them live simultaneously or
+    the per-layer overlap claim is vacuous."""
     model, params, local, prompts = setup
     world = _world()
     tp = TPServeEngine(model, params, world=world, max_len=MAX_LEN,
                        local=local)
     tp.generate(prompts, 3, greedy=True)
-    floor = 1 + model.cfg.n_layers + (1 if model.cfg.family == "moe" else 0)
+    floor = 1 + model.cfg.n_layers
     assert world.stats_snapshot()["peak_live_collectives"] >= floor
 
 
@@ -168,20 +168,10 @@ def test_ragged_prompts_match_unpadded_runs(setup):
         prompts[i, :lens[i]] = row
     out = local.generate(prompts, 4, greedy=True,
                          prompt_lens=np.array(lens))
-    if model.cfg.family == "dense":
-        for i, row in enumerate(rows):
-            solo = local.generate(row[None, :], 4, greedy=True)
-            assert np.array_equal(out[i, S:], solo[0, lens[i]:]), \
-                f"row {i} (len {lens[i]}) diverged from its unpadded run"
-    else:
-        # MoE expert-capacity contention couples rows within a batch
-        # (a row's token can be dropped because ANOTHER row routed to
-        # the same expert), so solo equivalence is defined only for
-        # dense models; the ragged path must still be schedule-
-        # deterministic — identical calls, identical bytes.
-        out2 = local.generate(prompts, 4, greedy=True,
-                              prompt_lens=np.array(lens))
-        assert np.array_equal(out, out2)
+    for i, row in enumerate(rows):
+        solo = local.generate(row[None, :], 4, greedy=True)
+        assert np.array_equal(out[i, S:], solo[0, lens[i]:]), \
+            f"row {i} (len {lens[i]}) diverged from its unpadded run"
 
 
 def test_generate_overflow_and_bad_lens_raise_valueerror(setup):
